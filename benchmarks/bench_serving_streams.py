@@ -8,12 +8,13 @@ slot grid triggers **zero recompilation** (jit cache size stays 1) — the
 serving analogue of the continuous batcher's static-shape discipline.
 
 ``--devices N`` sweeps the sharded slot grid: the same workload is driven
-with the slot axis sharded over 1, 2, ..., N host devices (each count in a
-fresh subprocess, since XLA pins the device count at init) and events/s
-scaling vs the 1-device baseline is reported. On a CPU host the "devices"
-share physical cores, so this validates the sharded path's overhead and
-mechanics rather than demonstrating real speedup — on a multi-chip host
-the same sweep reports true slot-throughput scaling.
+with the slot axis sharded over the first 1, 2, ..., N of this process's
+devices (``jax.devices()[:n]`` — one process, so a chip is never held by
+a parent while a child waits for it) and events/s scaling vs the 1-device
+baseline is reported. Each row names its platform and ``device_kind``. On
+a CPU host (``XLA_FLAGS=--xla_force_host_platform_device_count=N``) the
+"devices" share physical cores, so this validates the sharded path's
+overhead and mechanics rather than demonstrating real speedup.
 
 ``--evolve EVERY`` drives the same workload with and without the live
 topology service (DSST prune/regrow epochs every EVERY grid steps, hot
@@ -50,11 +51,6 @@ on-chip memory" analogue); dense bytes stay flat. A single quick pair
 also rides in the default ``run()`` rows.
 """
 from __future__ import annotations
-
-import json
-import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -435,51 +431,39 @@ def run_evolve(quick: bool = True, every: int = 0, frozen=None):
 SWEEP_STREAMS, SWEEP_SLOTS, SWEEP_WINDOWS = 64, 64, 2
 
 
-def _child_one_device_count(n_devices: int) -> None:
-    """Runs inside a subprocess whose XLA_FLAGS pinned ``n_devices``."""
-    from repro.launch.mesh import make_serving_mesh
-    mesh = make_serving_mesh(n_devices) if n_devices > 1 else None
-    sched = _drive(SWEEP_STREAMS, SWEEP_SLOTS, SWEEP_WINDOWS, mesh=mesh)
-    r = sched.telemetry.rollup()
-    print(json.dumps({
-        "devices": n_devices, "n_slots": sched.n_slots,
-        "events_per_s": r["events_per_s"],
-        "timesteps_per_s": r["timesteps_per_s"],
-        "p50_ms": r["p50_ms"], "p99_ms": r["p99_ms"],
-        "compiles": sched.n_compiles,
-    }))
-
-
 def run_devices_sweep(max_devices: int):
-    """Spawn one subprocess per device count (1, 2, 4, ..., max_devices)
-    and report events/s scaling of the sharded slot grid."""
+    """Drive the sharded slot grid over the first 1, 2, 4, ...,
+    ``max_devices`` devices of this process and report events/s scaling
+    against the 1-device row."""
+    from repro.launch.mesh import make_serving_mesh
+    devs = jax.devices()
+    if len(devs) < max_devices:
+        raise RuntimeError(
+            f"--devices {max_devices} needs {max_devices} devices, found "
+            f"{len(devs)}; on a CPU host set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={max_devices}")
     counts, d = [], 1
     while d < max_devices:
         counts.append(d)
         d *= 2
     counts.append(max_devices)
+    dev = f"{devs[0].platform}:{devs[0].device_kind}"
     rows, base = [], None
     for n in counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.bench_serving_streams",
-             "--_child", str(n)],
-            capture_output=True, text=True, env=env, timeout=1200)
-        if out.returncode != 0:
-            raise RuntimeError(f"devices={n} child failed:\n{out.stderr}")
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
+        mesh = make_serving_mesh(n) if n > 1 else None
+        sched = _drive(SWEEP_STREAMS, SWEEP_SLOTS, SWEEP_WINDOWS, mesh=mesh)
+        r = sched.telemetry.rollup()
         if base is None:
-            base = rec["events_per_s"]
+            base = r["events_per_s"]
         rows.append({
-            "name": f"serving/devices{n}_slots{rec['n_slots']}",
-            "us_per_call": rec["p50_ms"] * 1e3,
-            "derived": (f"events/s={rec['events_per_s']:.0f}"
-                        f" scale_x={rec['events_per_s'] / base:.2f}"
-                        f" ts/s={rec['timesteps_per_s']:.0f}"
-                        f" p99_ms={rec['p99_ms']:.2f}"
-                        f" compiles={rec['compiles']}"),
+            "name": f"serving/devices{n}_slots{sched.n_slots}",
+            "us_per_call": r["p50_ms"] * 1e3,
+            "derived": (f"device={dev}"
+                        f" events/s={r['events_per_s']:.0f}"
+                        f" scale_x={r['events_per_s'] / base:.2f}"
+                        f" ts/s={r['timesteps_per_s']:.0f}"
+                        f" p99_ms={r['p99_ms']:.2f}"
+                        f" compiles={sched.n_compiles}"),
         })
     return rows
 
@@ -488,7 +472,8 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, default=0,
-                    help="sweep the sharded slot grid over 1..N host devices")
+                    help="sweep the sharded slot grid over this process's "
+                         "first 1..N devices")
     ap.add_argument("--evolve", type=int, default=0, metavar="EVERY",
                     help="live topology epochs every EVERY grid steps, "
                          "vs a frozen-topology baseline")
@@ -511,12 +496,9 @@ if __name__ == "__main__":
                          "autopilot in the QoS A/B (off: fixed depth 1)")
     ap.add_argument("--json", default="", metavar="PATH",
                     help="also write the rows as a repro-bench/1 artifact")
-    ap.add_argument("--_child", type=int, default=0, help=argparse.SUPPRESS)
     args = ap.parse_args()
     rows = None
-    if args._child:
-        _child_one_device_count(args._child)
-    elif args.tiers is not None or args.adaptive is not None:
+    if args.tiers is not None or args.adaptive is not None:
         rows = run_qos(quick=True, tiers=(args.tiers != "off"),
                        adaptive=(args.adaptive != "off"))
     elif args.density:

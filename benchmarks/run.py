@@ -21,11 +21,16 @@ Modules:
 
 ``--dryrun`` only verifies every module imports and registers a ``run``
 callable — the CI smoke step that keeps registration from rotting.
+
+Compiled programs persist across runs in JAX's compilation cache: the
+directory ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+``<repo>/.jax_cache``.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -77,6 +82,10 @@ def main() -> None:
                     help="also write a machine-readable results artifact")
     args = ap.parse_args()
     quick = not args.full
+
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     from . import (bench_backend, bench_fig3_serdes, bench_fig4_ossl,
                    bench_fig5_dsst, bench_fig6_datapath, bench_fig7_tasks,

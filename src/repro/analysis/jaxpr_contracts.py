@@ -44,15 +44,20 @@ from jax import tree_util as jtu
 # chunk step would make the slot-axis shard_map results depend on the
 # device count — the exact failure mode the zero-collectives contract
 # forbids. Names are matched after stripping a trailing version digit
-# (``psum2`` -> ``psum``), so jax renames don't silently blind the check.
-# ``pbroadcast`` is deliberately absent: shard_map's check_rep machinery
-# inserts it as a device-local replication-accounting no-op, so flagging
-# it would false-positive on communication-free bodies.
+# (``psum2`` -> ``psum``) and the variant affixes jax adds under
+# ``shard_map``'s varying-manual-axes typing (``psum_invariant``,
+# ``all_gather_reduced``, ``unreduced_psum``, ``ragged_all_to_all``), so
+# jax renames don't silently blind the check. ``pbroadcast`` and ``pvary``
+# are deliberately absent: they are device-local replication-typing
+# no-ops, so flagging them would false-positive on communication-free
+# bodies.
 COLLECTIVE_PRIMITIVES = frozenset({
     "psum", "pmax", "pmin", "pmean", "ppermute", "pshuffle",
     "all_gather", "all_to_all", "pgather", "reduce_scatter", "psum_scatter",
-    "pdot",
+    "pdot", "psend", "precv",
 })
+_VARIANT_PREFIXES = ("unreduced_", "ragged_")
+_VARIANT_SUFFIXES = ("_invariant", "_reduced")
 
 
 # --------------------------------------------------------------------------
@@ -234,7 +239,14 @@ def check(fn, args: Sequence[Any], contracts: Sequence[Contract], *,
 # --------------------------------------------------------------------------
 
 def _base_prim_name(name: str) -> str:
-    return name[:-1] if name and name[-1].isdigit() else name
+    name = name.rstrip("0123456789")
+    for pre in _VARIANT_PREFIXES:
+        if name.startswith(pre):
+            name = name[len(pre):]
+    for suf in _VARIANT_SUFFIXES:
+        if name.endswith(suf):
+            name = name[:-len(suf)]
+    return name
 
 
 def no_collectives(axis: Optional[str] = None) -> Contract:

@@ -18,19 +18,29 @@ Two structural decisions:
   depth study and the ROADMAP's sharded-slot-grid work both need this.
 
 * **Backend seam.**  ``SNNConfig.backend`` selects how the three inner ops
-  (forward current, fused LIF step, WU outer product) are computed:
+  (forward current, fused LIF step, WU outer product) are computed, and
+  :func:`make_backend` is the only place that decides — never the platform
+  the program happens to run on:
 
-  - ``"ref"``             — pure jnp on dense masked weights (default);
-  - ``"pallas"``          — route through ``kernels/nm_spmm``, ``kernels/lif``
-                            and ``kernels/wu_outer``; real Pallas kernels on
-                            TPU, their jnp oracles elsewhere.  The compact
-                            N:M layout (values + block indices) is built from
-                            the mask at scan entry and carried *alongside*
-                            the mask through the time scan — training updates
-                            land directly in compact storage via
-                            ``wu_outer`` and are densified once per sample;
-  - ``"pallas-interpret"`` — same routing with ``interpret=True`` everywhere,
-                            the CPU-CI correctness mode for kernel parity.
+  - ``"ref"``             — pure jnp (default), lowered by XLA for whatever
+                            device runs it (the TPU's own XLA on a chip, not
+                            a CPU fallback). Training uses dense masked
+                            weights; a compact serving rep goes through the
+                            jnp ``nm_spmm`` reference;
+  - ``"pallas"``          — the compiled Mosaic kernels of ``kernels/nm_spmm``,
+                            ``kernels/lif`` and ``kernels/wu_outer``
+                            (``interpret=False``). The compact N:M layout
+                            (values + block indices) is built from the mask
+                            at scan entry and carried through the time scan —
+                            training updates land directly in compact storage
+                            via ``wu_outer`` and are densified once per
+                            sample. Only N:M specs whose ``block`` and
+                            ``out_tile`` are multiples of 128 tile onto the
+                            kernels; :func:`make_backend` refuses any other
+                            (the paper's element spec among them);
+  - ``"pallas-interpret"`` — same routing in Pallas interpret mode, the CPU
+                            correctness mode for kernel parity (tests only;
+                            interpret mode does not enforce the tiling).
 """
 from __future__ import annotations
 
@@ -184,20 +194,39 @@ def unstack_params(params, cfg):
 class Backend:
     name: str
     use_kernels: bool     # route through kernels/{nm_spmm,lif,wu_outer}
-    force_pallas: bool
-    interpret: bool
+    interpret: bool       # ...in Pallas interpret mode (tests)
+
+
+# Mosaic tiles a block's last two dims in multiples of (8, 128) unless a
+# dim spans the whole array; the nm_spmm x/out blocks are (rows, block) and
+# (rows, out_tile), wu_outer's (rows, block) and (rows, out_tile).
+KERNEL_LANE = 128
 
 
 def make_backend(cfg) -> Backend:
     """Resolve ``cfg.backend`` ("ref" | "pallas" | "pallas-interpret") to
-    the engine's static :class:`Backend` dispatch record."""
+    the engine's static :class:`Backend` dispatch record.
+
+    ``"pallas"`` is refused with a ``ValueError`` when the config's N:M
+    spec cannot tile onto the compiled kernels (``block`` or ``out_tile``
+    not a multiple of 128) — here, rather than deep in Mosaic lowering.
+    """
     name = getattr(cfg, "backend", "ref")
     if name == "ref":
-        return Backend("ref", False, False, False)
+        return Backend("ref", False, False)
     if name == "pallas":
-        return Backend("pallas", True, False, False)
+        for fan_in in sorted(set(cfg.layer_fanins)):
+            spec = cfg.spec(fan_in)
+            if spec.block % KERNEL_LANE or spec.out_tile % KERNEL_LANE:
+                raise ValueError(
+                    f"backend='pallas' needs an N:M spec whose block and "
+                    f"out_tile are multiples of {KERNEL_LANE} to tile onto "
+                    f"the compiled kernels; fan-in {fan_in} has "
+                    f"block={spec.block}, out_tile={spec.out_tile}. Use "
+                    f"backend='ref' (XLA on the same device) for this spec")
+        return Backend("pallas", True, False)
     if name == "pallas-interpret":
-        return Backend("pallas-interpret", True, True, True)
+        return Backend("pallas-interpret", True, True)
     raise ValueError(f"unknown backend {name!r}; expected one of {BACKENDS}")
 
 
@@ -292,20 +321,23 @@ def finalize_weights(wrep, cfg, backend: Backend) -> jax.Array:
 def fwd_current(backend: Backend, pre, w_l, delta_l):
     """Forward synaptic current for one layer: ``pre @ w`` (+ slot deltas).
 
-    Dispatch is on the weight rep's keys: a compact rep (``"wc"``) routes
-    through ``nm_spmm`` regardless of backend (the jnp reference off-TPU),
-    and compact per-slot deltas (rank 5: ``[S, J, T, bk, bo]``) contract
-    through ``nm_spmm_deltas`` on the same kept-block ids — no dense
-    ``[K, N]`` tensor exists anywhere on this path.
+    Dispatch is on the weight rep's keys: a compact rep (``"wc"``) goes
+    through the ``nm_spmm`` kernel under a kernel backend and through its
+    jnp reference under ``"ref"``, and compact per-slot deltas (rank 5:
+    ``[S, J, T, bk, bo]``) contract through ``nm_spmm_deltas`` on the same
+    kept-block ids — no dense ``[K, N]`` tensor exists anywhere on this
+    path.
     """
     if "wc" in w_l:
-        from repro.kernels.nm_spmm import ops as nm_ops
-        cur = nm_ops.nm_spmm_batched(pre, w_l["wc"], w_l["idx"],
-                                     interpret=backend.interpret,
-                                     force_pallas=backend.force_pallas)
+        from repro.kernels.nm_spmm import ops as nm_ops, ref as nm_ref
+        if backend.use_kernels:
+            cur = nm_ops.nm_spmm_batched(pre, w_l["wc"], w_l["idx"],
+                                         interpret=backend.interpret)
+        else:
+            cur = nm_ref.nm_spmm(pre, w_l["wc"], w_l["idx"])
         if delta_l is not None:
             if delta_l.ndim == 5:
-                cur = cur + nm_ops.nm_spmm_deltas(pre, delta_l, w_l["idx"])
+                cur = cur + nm_ref.nm_spmm_deltas(pre, delta_l, w_l["idx"])
             else:
                 cur = cur + jnp.einsum("sk,skn->sn", pre, delta_l)
         return cur
@@ -322,8 +354,7 @@ def lif(backend: Backend, cfg, v, tr, current):
         from repro.kernels.lif import ops as lif_ops
         return lif_ops.lif_step(v, tr, current, alpha=cfg.alpha,
                                 beta=cfg.beta, theta=cfg.theta,
-                                interpret=backend.interpret,
-                                force_pallas=backend.force_pallas)
+                                interpret=backend.interpret)
     return lif_step(v, tr, current, alpha=cfg.alpha, beta=cfg.beta,
                     theta=cfg.theta)
 
@@ -339,8 +370,7 @@ def train_wu(backend: Backend, cfg, w_l, pre_trace, mod, scale):
         spec = cfg.spec(cfg.layer_fanins[0])
         dwc = wu_ops.wu_outer(pre_trace, mod, w_l["idx"], scale,
                               bk=spec.block, bo=spec.out_tile,
-                              interpret=backend.interpret,
-                              force_pallas=backend.force_pallas)
+                              interpret=backend.interpret)
         return {**w_l, "wc": w_l["wc"] + dwc}
     dw = scale * (pre_trace.T @ mod)
     return {**w_l, "w": w_l["w"] + dw * w_l["mask_f"]}
@@ -438,12 +468,11 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
             # compact per-slot WU: the outer product lands only in kept
             # blocks — sparse in compute AND storage (the paper's
             # activity-dependent sparse WU)
-            from repro.kernels.wu_outer import ops as wu_ops
+            from repro.kernels.wu_outer import ref as wu_ref
             spec = cfg.spec(geo.fanins[0])
             scale = jnp.where(wu_on, cfg.lr, 0.0)
-            delta_new = xs.delta + wu_ops.wu_outer_slots(
-                pre_tr, mod, xs.w["idx"], scale,
-                bk=spec.block, bo=spec.out_tile)
+            delta_new = xs.delta + wu_ref.wu_outer_slots(
+                pre_tr, mod, xs.w["idx"], scale, spec.block, spec.out_tile)
         else:
             scale = jnp.where(wu_on, cfg.lr, 0.0)[:, None, None]
             dw = scale * pre_tr[:, :, None] * mod[:, None, :]

@@ -84,9 +84,10 @@ class SNNConfig:
     dsst_enabled: bool = True  # False = static sparse training baseline
     # gating
     gating: gating_lib.GatingConfig = dataclasses.field(default_factory=gating_lib.GatingConfig)
-    # compute backend for the timestep engine (core/engine.py):
-    # "ref" (jnp), "pallas" (kernels; real Pallas on TPU), "pallas-interpret"
-    # (kernels emulated everywhere — the CPU-CI parity mode).
+    # compute backend for the timestep engine (core/engine.make_backend):
+    # "ref" (jnp, XLA on whatever device runs it), "pallas" (the compiled
+    # Mosaic kernels; needs a 128-aligned N:M spec), "pallas-interpret"
+    # (the kernels in interpret mode — the CPU parity mode for tests).
     backend: str = "ref"
 
     def spec(self, fan_in: int) -> NMSpec:

@@ -5,8 +5,9 @@ Each kernel ships as a triple (DESIGN.md §7):
 * ``<name>/kernel.py`` — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling,
   written for TPU (MXU-aligned tiles, scalar-prefetched index tables) and
   validated in ``interpret=True`` mode on CPU;
-* ``<name>/ops.py``    — the jit'd public wrapper (padding, custom_vjp,
-  interpret/TPU dispatch);
+* ``<name>/ops.py``    — the public wrapper (padding, custom_vjp) around the
+  kernel, with an ``interpret`` flag; whether a kernel runs at all is
+  decided by the engine's backend seam (``core.engine.make_backend``);
 * ``<name>/ref.py``    — the pure-jnp oracle the tests sweep against.
 
 Kernels:

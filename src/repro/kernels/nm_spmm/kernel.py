@@ -78,20 +78,12 @@ def nm_spmm_pallas(
         out_specs=pl.BlockSpec((bm, bo), o_map),
         scratch_shapes=[pltpu.VMEM((bm, bo), jnp.float32)],
     )
-    kwargs = {}
-    if not interpret:
-        # rows/tiles parallel; kept-block accumulation revisits the out tile.
-        try:
-            kwargs["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except AttributeError:  # older pallas API
-            kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     return pl.pallas_call(
         functools.partial(_kernel, n_kept=t),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, j * bo), x.dtype),
+        # rows/tiles parallel; kept-block accumulation revisits the out tile
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(idx, x, w_compact)

@@ -1,9 +1,12 @@
 """Public op: block-N:M sparse matmul with sparse-to-sparse gradients.
 
-``nm_spmm(x, w_compact, idx)`` dispatches to the Pallas kernel (TPU, or
-interpret mode when forced) or to the jnp reference (CPU default — interpret
-mode is a correctness tool, not a fast path), wrapped in a ``custom_vjp``
-whose backward pass **never materialises the dense weight matrix**:
+Everything here runs the Pallas kernel (``kernel.py``); ``interpret=True``
+runs it in Pallas interpret mode, the CPU test mode. Whether the kernel is
+used at all is decided by the caller — the engine's backend seam
+(``core.engine.make_backend``) — never by the platform; the pure-jnp
+reference lives in ``ref.py``. ``nm_spmm(x, w_compact, idx)`` wraps the
+kernel in a ``custom_vjp`` whose backward pass **never materialises the
+dense weight matrix**:
 
 * ``dx``        — transposed sparse matmul, assembled block-wise;
 * ``dw_compact``— gradient *only for materialised blocks* (gather x blocks,
@@ -18,33 +21,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import ref
 from .kernel import nm_spmm_pallas
 
 
-def _use_pallas(force_pallas: bool) -> bool:
-    if force_pallas:
-        return True
-    return jax.default_backend() == "tpu"
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def nm_spmm(x, w_compact, idx, interpret=False):
+    return nm_spmm_batched(x, w_compact, idx, interpret=interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def nm_spmm(x, w_compact, idx, interpret=False, force_pallas=False):
-    return _fwd_impl(x, w_compact, idx, interpret, force_pallas)
+def _fwd(x, w_compact, idx, interpret):
+    return (nm_spmm_batched(x, w_compact, idx, interpret=interpret),
+            (x, w_compact, idx))
 
 
-def _fwd_impl(x, w_compact, idx, interpret, force_pallas):
-    if _use_pallas(force_pallas):
-        return nm_spmm_pallas(x, w_compact, idx,
-                              interpret=interpret or jax.default_backend() != "tpu")
-    return ref.nm_spmm(x, w_compact, idx)
-
-
-def _fwd(x, w_compact, idx, interpret, force_pallas):
-    return _fwd_impl(x, w_compact, idx, interpret, force_pallas), (x, w_compact, idx)
-
-
-def _bwd(interpret, force_pallas, res, dy):
+def _bwd(interpret, res, dy):
     x, w_compact, idx = res
     j, t, bk, bo = w_compact.shape
     b, k = x.shape
@@ -93,38 +83,16 @@ def make_compact(w_dense: jax.Array, unit_mask: jax.Array, bk: int, bo: int,
     return w_compact, idx
 
 
-def nm_spmm_deltas(x, delta_compact, idx):
-    """Per-slot compact delta matmul: ``y[s] = x[s] @ densify(delta[s])``.
-
-    ``x [S, K]`` with per-slot compact deltas ``[S, J, T, bk, bo]`` sharing
-    one ``idx [J, T]`` (every stream lives on the fleet's topology). The
-    gather mirrors ``ref.nm_spmm``; only the batch axis rides along on the
-    weight operand. Used by the serving hot path so the per-stream delta
-    current never round-trips through a dense ``[K, N]`` tensor.
+def nm_spmm_batched(x, w_compact, idx, *, interpret: bool = False):
+    """Row-count-agnostic kernel forward (no custom VJP): pads the row
+    dimension to the kernel's ``bm`` tile. The engine's local learning
+    rules never backprop through the forward matmul, so its hot path calls
+    this directly instead of :func:`nm_spmm`.
     """
-    s, k = x.shape
-    _, j, t, bk, bo = delta_compact.shape
-    xb = x.reshape(s, k // bk, bk)
-    xg = xb[:, idx, :]                                      # [S, J, T, bk]
-    y = jnp.einsum("sjtk,sjtko->sjo", xg, delta_compact)
-    return y.reshape(s, j * bo)
-
-
-def nm_spmm_batched(x, w_compact, idx, *, interpret: bool = False,
-                    force_pallas: bool = False):
-    """Row-count-agnostic forward dispatch (no custom VJP).
-
-    The engine's local learning rules never backprop through the forward
-    matmul, so this skips the ``custom_vjp`` wrapper and simply pads the
-    row dimension to the kernel's ``bm`` tile before dispatching.
-    """
-    if not _use_pallas(force_pallas):
-        return ref.nm_spmm(x, w_compact, idx)
     b = x.shape[0]
     bm = 128 if b >= 128 else 8
     pad = (-b) % bm
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
-    y = nm_spmm_pallas(x, w_compact, idx, bm=bm,
-                       interpret=interpret or jax.default_backend() != "tpu")
+    y = nm_spmm_pallas(x, w_compact, idx, bm=bm, interpret=interpret)
     return y[:b]

@@ -37,6 +37,23 @@ def nm_spmm(x: jax.Array, w_compact: jax.Array, idx: jax.Array) -> jax.Array:
     return y.reshape(b, j * bo)
 
 
+def nm_spmm_deltas(x, delta_compact, idx):
+    """Per-slot compact delta matmul: ``y[s] = x[s] @ densify(delta[s])``.
+
+    ``x [S, K]`` with per-slot compact deltas ``[S, J, T, bk, bo]`` sharing
+    one ``idx [J, T]`` (every stream lives on the fleet's topology). The
+    gather mirrors :func:`nm_spmm`; only the batch axis rides along on the
+    weight operand. Used by the serving hot path so the per-stream delta
+    current never round-trips through a dense ``[K, N]`` tensor.
+    """
+    s, k = x.shape
+    _, j, t, bk, bo = delta_compact.shape
+    xb = x.reshape(s, k // bk, bk)
+    xg = xb[:, idx, :]                                      # [S, J, T, bk]
+    y = jnp.einsum("sjtk,sjtko->sjo", xg, delta_compact)
+    return y.reshape(s, j * bo)
+
+
 def nm_spmm_dense_ref(x: jax.Array, w_compact: jax.Array, idx: jax.Array) -> jax.Array:
     """Second, independent oracle via densify (used in tests)."""
     k = x.shape[1]

@@ -1,19 +1,22 @@
-"""Public gated sparse-WU op (padding + dispatch)."""
+"""Public gated sparse-WU op: padding to the kernel's row tile, then the
+kernel.
+
+Whether the kernel runs at all is the caller's decision (the engine's
+backend seam); ``interpret=True`` runs it in Pallas interpret mode, the
+CPU test mode. The pure-jnp references — including the per-slot serving
+update ``wu_outer_slots``, which has no kernel — live in ``ref.py``.
+"""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
-from . import ref
 from .kernel import wu_outer_pallas
 
 
 def wu_outer(pre, mod, idx, scale, *, bk: int, bo: int,
-             interpret: bool = False, force_pallas: bool = False):
+             interpret: bool = False):
     """ΔW_compact = scale · gatherᵢ(pre) ⊗ mod, compact layout only."""
     scale = jnp.asarray(scale, pre.dtype)
-    if not (force_pallas or jax.default_backend() == "tpu"):
-        return ref.wu_outer(pre, mod, idx, scale, bk, bo)
     b = pre.shape[0]
     bb = min(128, b)
     pad = (-b) % bb
@@ -21,18 +24,4 @@ def wu_outer(pre, mod, idx, scale, *, bk: int, bo: int,
         pre = jnp.pad(pre, ((0, pad), (0, 0)))
         mod = jnp.pad(mod, ((0, pad), (0, 0)))
     return wu_outer_pallas(pre, mod, idx, scale, bk=bk, bo=bo, bb=bb,
-                           interpret=interpret or jax.default_backend() != "tpu")
-
-
-def wu_outer_slots(pre, mod, idx, scale, *, bk: int, bo: int,
-                   interpret: bool = False, force_pallas: bool = False):
-    """Per-slot compact WU: each slot keeps its own ``[J, T, bk, bo]`` update.
-
-    jnp-only for now — the per-slot variant has no batch reduction so it is
-    bandwidth-bound; a TPU mapping would vmap the WU kernel over slots.
-    ``interpret``/``force_pallas`` are accepted for signature parity with
-    ``wu_outer`` and ignored.
-    """
-    del interpret, force_pallas
-    scale = jnp.asarray(scale, pre.dtype)
-    return ref.wu_outer_slots(pre, mod, idx, scale, bk, bo)
+                           interpret=interpret)

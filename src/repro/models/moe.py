@@ -172,7 +172,6 @@ def _moe_apply_shardmap(p, x, cfg: ModelConfig, sp, ctx) -> Tuple[jax.Array, Dic
       contribute zeros). Comm = one [n_local, D] all-reduce instead of the
       partitioner's buffer replication.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, tp = ctx.mesh, ctx.tp_axis
@@ -225,7 +224,7 @@ def _moe_apply_shardmap(p, x, cfg: ModelConfig, sp, ctx) -> Tuple[jax.Array, Dic
         return out.reshape(xl.shape), aux
 
     wl = {k: p[k] for k in ("w1", "w2", "w3") if k in p}
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, aux = fn(x, p["router"], wl)
     return out, aux

@@ -119,11 +119,10 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
         body, jit_kw = step, {}
         validate = lambda n_slots: None
     else:
-        from jax.experimental.shard_map import shard_map
         from repro.launch import sharding as SH
         in_specs, out_specs = SH.chunk_step_specs(want_factors)
-        body = shard_map(step, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        body = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
         in_sh, out_sh = SH.chunk_step_shardings(mesh, want_factors)
         jit_kw = {"in_shardings": in_sh, "out_shardings": out_sh}
         validate = lambda n_slots: SH.check_slot_divisible(n_slots, mesh)

@@ -327,9 +327,17 @@ class StreamScheduler:
         dense ``self.params`` — the mask-free compact rep in compact mode —
         and re-measure the resident serving bytes. Host-side; runs at
         construction and after every topology swap (the only times the base
-        weights change)."""
+        weights change).
+
+        Under a mesh the exec params are placed replicated on it every
+        time: an epoch leaves them committed to the mesh while the
+        constructor's are not, and the chunk fn's jit would trace that
+        difference as a new input type (a recompile per first swap)."""
         self._exec_params = (serving_params(self.params, self.cfg)
                              if self.compact else self.params)
+        if self.mesh is not None:
+            self._exec_params = jax.device_put(
+                self._exec_params, sharding.replicated(self.mesh))
         self._params_bytes = sum(
             int(leaf.nbytes)
             for leaf in jax.tree_util.tree_leaves(self._exec_params))

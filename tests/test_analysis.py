@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -189,7 +189,7 @@ def test_dtype_discipline_catches_f64():
     def planted(x):
         return x.astype(jnp.float64) + np.float64(1.0)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         r = analysis.check(planted, (jnp.zeros((3,), jnp.float32),),
                            [analysis.dtype_discipline()])
     assert not r.ok

@@ -32,8 +32,8 @@ from repro.core import engine, topology
 from repro.core.dsst import DSSTConfig
 from repro.core.snn import (SNNConfig, init_params, init_stream_deltas,
                             init_stream_state, run_chunk, serving_params)
-from repro.kernels.nm_spmm import ops as nm_ops
-from repro.kernels.wu_outer import ops as wu_ops
+from repro.kernels.nm_spmm import ops as nm_ops, ref as nm_ref
+from repro.kernels.wu_outer import ref as wu_ref
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -126,7 +126,7 @@ def test_compact_weights_match_forward():
                                   params["hidden"]["mask"], cfg)
     dm = topology.dense_masks(params["hidden"]["mask"], cfg)
     x = _events(5, 4, 1, cfg)[:, 0, :]           # [4, n_in] spikes
-    y_c = nm_ops.nm_spmm_batched(x, wrep["wc"][0], wrep["idx"][0])
+    y_c = nm_ref.nm_spmm(x, wrep["wc"][0], wrep["idx"][0])
     y_d = x @ np.asarray(params["hidden"]["w"][0] * dm[0])
     np.testing.assert_allclose(np.asarray(y_c), np.asarray(y_d), atol=1e-6)
 
@@ -240,8 +240,8 @@ def test_wu_outer_slots_bitwise_vs_dense_at_kept_coords():
     mod = jnp.asarray(r.standard_normal((S, cfg.n_hidden)).astype(np.float32))
     scale = jnp.asarray(r.uniform(0, 0.1, S).astype(np.float32))
 
-    dwc = wu_ops.wu_outer_slots(pre, mod, idx, scale,
-                                bk=spec.block, bo=spec.out_tile)
+    dwc = wu_ref.wu_outer_slots(pre, mod, idx, scale, spec.block,
+                                spec.out_tile)
     dense = (scale[:, None] * pre)[:, :, None] * mod[:, None, :] * dm[None]
     got = engine.densify_deltas(
         dwc[:, None], idx[None], dataclasses.replace(cfg, n_layers=1))[:, 0]
@@ -399,7 +399,7 @@ def test_compact_chunk_step_8device_bit_identical():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax
         from repro.core.snn import (SNNConfig, init_params, init_stream_state,

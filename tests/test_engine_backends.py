@@ -121,3 +121,25 @@ def test_run_chunk_backend_parity(params):
 def test_unknown_backend_rejected():
     with pytest.raises(ValueError):
         engine.make_backend(dataclasses.replace(CFG, backend="cuda"))
+
+
+def test_pallas_backend_accepts_128_aligned_spec():
+    """The compiled-kernel backend is granted exactly when every layer's
+    N:M blocks tile onto Mosaic (multiples of 128 on both axes)."""
+    from repro.core.sparsity import NMSpec
+
+    class Cfg:
+        backend = "pallas"
+        layer_fanins = (512, 512)
+
+        def __init__(self, block, out_tile):
+            self._spec = NMSpec(n=1, m=4, block=block, out_tile=out_tile)
+
+        def spec(self, fan_in):
+            return self._spec
+
+    assert engine.make_backend(Cfg(128, 256)) == engine.Backend(
+        "pallas", use_kernels=True, interpret=False)
+    for block, out_tile in [(1, 1), (128, 64), (64, 128)]:
+        with pytest.raises(ValueError, match="multiples of 128"):
+            engine.make_backend(Cfg(block, out_tile))

@@ -53,7 +53,7 @@ def test_nm_spmm_custom_vjp_matches_dense_autodiff():
     x, w, wc, idx, mask, spec = _mk_sparse(1, 64, 32, 8, 16, 1, 2, jnp.float32)
     dy = jax.random.normal(jax.random.PRNGKey(7), (16, 32))
 
-    f = lambda x_, wc_: (nm_ops.nm_spmm(x_, wc_, idx) * dy).sum()
+    f = lambda x_, wc_: (nm_ops.nm_spmm(x_, wc_, idx, True) * dy).sum()
     gx, gwc = jax.grad(f, argnums=(0, 1))(x, wc)
     fd = lambda x_, wd_: ((x_ @ wd_) * dy).sum()
     gxd, gwd = jax.grad(fd, argnums=(0, 1))(x, nm_ref.densify(wc, idx, 64))
@@ -77,7 +77,7 @@ def test_lif_kernel_sweep(shape, dtype):
     tr = jax.random.uniform(ks[1], shape).astype(dtype)
     cur = jax.random.normal(ks[2], shape).astype(dtype)
     kw = dict(alpha=0.9, beta=0.85, theta=1.0)
-    got = lif_ops.lif_step(v, tr, cur, force_pallas=True, interpret=True, **kw)
+    got = lif_ops.lif_step(v, tr, cur, interpret=True, **kw)
     want = lif_ref.lif_step(v, tr, cur, **kw)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g, np.float32),

@@ -9,7 +9,7 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(args, extra_env=None, timeout=560):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(extra_env or {})
     return subprocess.run([sys.executable, "-m", "repro.launch.launcher"] + args,
                           capture_output=True, text=True, env=env,

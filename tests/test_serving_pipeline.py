@@ -145,7 +145,7 @@ def test_pipeline_8device_sharded_parity(params):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax
         from repro.core.snn import SNNConfig, init_params

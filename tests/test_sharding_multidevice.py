@@ -17,7 +17,7 @@ def _run(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=560)
     assert out.returncode == 0, out.stdout + "\n" + out.stderr
@@ -95,7 +95,7 @@ def test_compressed_dp_allreduce_shardmap():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.runtime.compression import CompressionConfig, compress, decompress
 
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
