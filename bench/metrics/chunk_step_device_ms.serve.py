@@ -1,0 +1,12 @@
+"""Device time of the serving chunk program per run, in ms, from the
+trace: the ``jit_chunk_fn`` module's executions inside the traced
+stretch."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    hit = ctx.trace.program("jit_chunk_fn")
+    if hit is None or not hit[1]:
+        return None
+    return hit[0] / hit[1] * 1e3
