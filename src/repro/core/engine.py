@@ -326,25 +326,26 @@ def fwd_current(backend: Backend, pre, w_l, delta_l):
     jnp reference under ``"ref"``, and compact per-slot deltas (rank 5:
     ``[S, J, T, bk, bo]``) contract through ``nm_spmm_deltas`` on the same
     kept-block ids — no dense ``[K, N]`` tensor exists anywhere on this
-    path.
+    path. The base current runs under the named scope ``si_base``, the
+    per-slot delta current under ``si_delta``.
     """
-    if "wc" in w_l:
+    compact = "wc" in w_l
+    if compact:
         from repro.kernels.nm_spmm import ops as nm_ops, ref as nm_ref
-        if backend.use_kernels:
+    with jax.named_scope("si_base"):
+        if not compact:
+            cur = pre @ w_l["w"]
+        elif backend.use_kernels:
             cur = nm_ops.nm_spmm_batched(pre, w_l["wc"], w_l["idx"],
                                          interpret=backend.interpret)
         else:
             cur = nm_ref.nm_spmm(pre, w_l["wc"], w_l["idx"])
-        if delta_l is not None:
-            if delta_l.ndim == 5:
-                cur = cur + nm_ref.nm_spmm_deltas(pre, delta_l, w_l["idx"])
-            else:
-                cur = cur + jnp.einsum("sk,skn->sn", pre, delta_l)
+    if delta_l is None:
         return cur
-    cur = pre @ w_l["w"]
-    if delta_l is not None:
-        cur = cur + jnp.einsum("sk,skn->sn", pre, delta_l)
-    return cur
+    with jax.named_scope("si_delta"):
+        if compact and delta_l.ndim == 5:
+            return cur + nm_ref.nm_spmm_deltas(pre, delta_l, w_l["idx"])
+        return cur + jnp.einsum("sk,skn->sn", pre, delta_l)
 
 
 def lif(backend: Backend, cfg, v, tr, current):
@@ -439,44 +440,55 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
     magnitudes (``pre_mag``/``post_mag``) are emitted at all. A non-evolving
     fleet passes False and the O(S·(K+N))-per-timestep factor arithmetic
     never enters the trace — it is compiled out, not just skipped.
+
+    Each stage runs under a ``jax.named_scope`` (``si_base``/``si_delta``
+    in :func:`fwd_current`, ``lif``, ``ossl``, ``gate``, ``wu_delta`` or
+    ``wu_base``, ``telemetry``, ``readout``), so the compiled ops carry
+    the model's names in their metadata and a device profile can be read
+    per stage.
     """
     g = cfg.gating
     st, pre, pre_tr = xs.st, carry.pre_spikes, carry.pre_trace
     col = (lambda c: c[:, None]) if serving else (lambda c: c)
 
     current = fwd_current(backend, pre, xs.w, xs.delta)
-    v, tr, s = lif(backend, cfg, st.v, st.tr, current)
-    tr_pc = jnp.where(col(t_row == t_pc), tr, st.tr_pc)
+    with jax.named_scope("lif"):
+        v, tr, s = lif(backend, cfg, st.v, st.tr, current)
+        tr_pc = jnp.where(col(t_row == t_pc), tr, st.tr_pc)
 
     # ---- OSSL three-factor WU, gated, concurrent with SI ----
-    mod = ossl_modulator(tr, tr_pc, st.tr_cc, v, cfg)
-    if serving:
-        ia = pre.mean(-1) if geo.uniform else pre.sum(-1) / xs.fanin
-        ss = _cos(tr, st.tr_cc)
-    else:
-        ia = pre.mean() if geo.uniform \
-            else pre.sum() / (pre.shape[0] * xs.fanin)
-        ss = _cos(tr, st.tr_cc).mean()
-    open_, new_mean = gating_lib.gate_decide(xs.ss_mean, ia, ss, g)
-    if serving:
-        open_ = open_ & valid
-        new_mean = jnp.where(valid, new_mean, xs.ss_mean)
-    wu_on = open_ & (t_row >= t_wu) & jnp.asarray(learn)
+    with jax.named_scope("ossl"):
+        mod = ossl_modulator(tr, tr_pc, st.tr_cc, v, cfg)
+    with jax.named_scope("gate"):
+        if serving:
+            ia = pre.mean(-1) if geo.uniform else pre.sum(-1) / xs.fanin
+            ss = _cos(tr, st.tr_cc)
+        else:
+            ia = pre.mean() if geo.uniform \
+                else pre.sum() / (pre.shape[0] * xs.fanin)
+            ss = _cos(tr, st.tr_cc).mean()
+        open_, new_mean = gating_lib.gate_decide(xs.ss_mean, ia, ss, g)
+        if serving:
+            open_ = open_ & valid
+            new_mean = jnp.where(valid, new_mean, xs.ss_mean)
+        wu_on = open_ & (t_row >= t_wu) & jnp.asarray(learn)
 
     if serving:
-        if xs.delta.ndim == 5:
-            # compact per-slot WU: the outer product lands only in kept
-            # blocks — sparse in compute AND storage (the paper's
-            # activity-dependent sparse WU)
-            from repro.kernels.wu_outer import ref as wu_ref
-            spec = cfg.spec(geo.fanins[0])
-            scale = jnp.where(wu_on, cfg.lr, 0.0)
-            delta_new = xs.delta + wu_ref.wu_outer_slots(
-                pre_tr, mod, xs.w["idx"], scale, spec.block, spec.out_tile)
-        else:
-            scale = jnp.where(wu_on, cfg.lr, 0.0)[:, None, None]
-            dw = scale * pre_tr[:, :, None] * mod[:, None, :]
-            delta_new = xs.delta + dw * xs.w["mask_f"][None]
+        with jax.named_scope("wu_delta"):
+            if xs.delta.ndim == 5:
+                # compact per-slot WU: the outer product lands only in kept
+                # blocks — sparse in compute AND storage (the paper's
+                # activity-dependent sparse WU)
+                from repro.kernels.wu_outer import ref as wu_ref
+                spec = cfg.spec(geo.fanins[0])
+                scale = jnp.where(wu_on, cfg.lr, 0.0)
+                delta_new = xs.delta + wu_ref.wu_outer_slots(
+                    pre_tr, mod, xs.w["idx"], scale, spec.block,
+                    spec.out_tile)
+            else:
+                scale = jnp.where(wu_on, cfg.lr, 0.0)[:, None, None]
+                dw = scale * pre_tr[:, :, None] * mod[:, None, :]
+                delta_new = xs.delta + dw * xs.w["mask_f"][None]
         w_new, opened_new, offered_new = xs.w, None, None
         if factors:
             # DSST factors for the live topology service: per-slot activity
@@ -488,21 +500,23 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
         else:
             pre_mag = post_mag = None   # frozen fleet: factors compiled out
     else:
-        scale = jnp.where(wu_on, cfg.lr / pre.shape[0], 0.0)
-        w_new = train_wu(backend, cfg, xs.w, pre_tr, mod, scale)
+        with jax.named_scope("wu_base"):
+            scale = jnp.where(wu_on, cfg.lr / pre.shape[0], 0.0)
+            w_new = train_wu(backend, cfg, xs.w, pre_tr, mod, scale)
         delta_new = None
         opened_new = xs.gate_opened + open_.astype(jnp.float32)
         offered_new = xs.gate_offered + 1.0
         pre_mag = post_mag = None   # training accumulates its own factors
 
     # ---- telemetry (energy model inputs), per row ----
-    late = (t_row >= t_wu) & valid if serving else (t_row >= t_wu)
-    offered = xs.fanin * cfg.n_hidden * xs.density
-    sop_fwd = carry.sop_fwd + pre.sum(-1) * cfg.n_hidden * xs.density
-    sop_wu_off = carry.sop_wu_off + offered * late
-    sop_wu = carry.sop_wu + offered * wu_on
-    loss = carry.loss + \
-        (-_cos(tr, tr_pc) + cfg.cc_weight * _cos(tr, st.tr_cc)) * late
+    with jax.named_scope("telemetry"):
+        late = (t_row >= t_wu) & valid if serving else (t_row >= t_wu)
+        offered = xs.fanin * cfg.n_hidden * xs.density
+        sop_fwd = carry.sop_fwd + pre.sum(-1) * cfg.n_hidden * xs.density
+        sop_wu_off = carry.sop_wu_off + offered * late
+        sop_wu = carry.sop_wu + offered * wu_on
+        loss = carry.loss + \
+            (-_cos(tr, tr_pc) + cfg.cc_weight * _cos(tr, st.tr_cc)) * late
 
     # invalid slots keep their exact previous state
     if serving:
@@ -512,7 +526,8 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
         tr_pc = jnp.where(vv, tr_pc, st.tr_pc)
         s = s * valid.astype(s.dtype)[:, None]
 
-    logits = carry.logits + tr @ xs.readout
+    with jax.named_scope("readout"):
+        logits = carry.logits + tr @ xs.readout
     new_carry = LayerCarry(
         pre_spikes=_pad_cols(s, geo.k_max),
         pre_trace=_pad_cols(tr, geo.k_max),
@@ -631,16 +646,17 @@ def scan_chunk(wrep, readout, deltas, layers: LayerState, x_tr,
         lc, ys = jax.lax.scan(partial(body, t_w, val), lc0, xs)
 
         # ---- per-slot window roll: final trace becomes the CC negative ----
-        at_end = val & (t_w == cfg.t_steps - 1)
-        endf = at_end[:, None]
-        rolled = LayerState(
-            v=jnp.where(endf, 0.0, ys.st.v),
-            tr=jnp.where(endf, 0.0, ys.st.tr),
-            tr_pc=jnp.where(endf, 0.0, ys.st.tr_pc),
-            tr_cc=jnp.where(endf, ys.st.tr, ys.st.tr_cc))
-        x_tr = jnp.where(endf, 0.0, x_tr)
-        samp = samp + at_end.astype(jnp.int32)
-        t_w = jnp.where(val, (t_w + 1) % cfg.t_steps, t_w)
+        with jax.named_scope("window_roll"):
+            at_end = val & (t_w == cfg.t_steps - 1)
+            endf = at_end[:, None]
+            rolled = LayerState(
+                v=jnp.where(endf, 0.0, ys.st.v),
+                tr=jnp.where(endf, 0.0, ys.st.tr),
+                tr_pc=jnp.where(endf, 0.0, ys.st.tr_pc),
+                tr_cc=jnp.where(endf, ys.st.tr, ys.st.tr_cc))
+            x_tr = jnp.where(endf, 0.0, x_tr)
+            samp = samp + at_end.astype(jnp.int32)
+            t_w = jnp.where(val, (t_w + 1) % cfg.t_steps, t_w)
 
         out = dict(logits=lc.logits, at_end=at_end, sop_fwd=lc.sop_fwd,
                    sop_wu=lc.sop_wu, sop_wu_off=lc.sop_wu_off,

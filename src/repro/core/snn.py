@@ -199,39 +199,41 @@ def run_sample(
     # ``topology.topology_epoch`` — the identical code path the serving
     # topology service runs between grid steps, honoring the decay schedule
     # through the traced sample index (lax.switch over static k levels).
-    pre_traces = [x_tr] + [layers.tr[l] for l in range(cfg.n_layers - 1)]
-    new_acc = []
-    for l, fan_in in enumerate(cfg.layer_fanins):
-        spec = cfg.spec(fan_in)
-        kb, jj = spec.unit_counts(fan_in, cfg.n_hidden)
-        pre_mag = jnp.abs(pre_traces[l]).mean(0)                      # [K]
-        mod = ossl_modulator(layers.tr[l], layers.tr_pc[l], layers.tr_cc[l],
-                             layers.v[l], cfg)
-        post_mag = jnp.abs(mod).mean(0)                               # [N]
-        pre_units = pre_mag.reshape(kb, -1).sum(-1)
-        new_acc.append(state.acc[l].update(pre_units, post_mag))
-
+    # Both run under the named scope ``dsst``.
     new_params = {"hidden": {"w": w_stacked, "mask": masks}, "readout": pr}
-    new_acc = tuple(new_acc)
-    if cfg.dsst_enabled and not cfg.dense and learn:
-        pre_stacked = jnp.stack([engine._pad_rows(a.pre, masks.shape[1])
-                                 for a in new_acc])                   # [L, KBmax]
-        post_stacked = jnp.stack([a.post for a in new_acc])           # [L, J]
+    with jax.named_scope("dsst"):
+        pre_traces = [x_tr] + [layers.tr[l] for l in range(cfg.n_layers - 1)]
+        new_acc = []
+        for l, fan_in in enumerate(cfg.layer_fanins):
+            spec = cfg.spec(fan_in)
+            kb, jj = spec.unit_counts(fan_in, cfg.n_hidden)
+            pre_mag = jnp.abs(pre_traces[l]).mean(0)                  # [K]
+            mod = ossl_modulator(layers.tr[l], layers.tr_pc[l],
+                                 layers.tr_cc[l], layers.v[l], cfg)
+            post_mag = jnp.abs(mod).mean(0)                           # [N]
+            pre_units = pre_mag.reshape(kb, -1).sum(-1)
+            new_acc.append(state.acc[l].update(pre_units, post_mag))
+        new_acc = tuple(new_acc)
+        if cfg.dsst_enabled and not cfg.dense and learn:
+            pre_stacked = jnp.stack([engine._pad_rows(a.pre, masks.shape[1])
+                                     for a in new_acc])           # [L, KBmax]
+            post_stacked = jnp.stack([a.post for a in new_acc])       # [L, J]
 
-        def do(args):
-            p, accs = args
-            p2, _ = topology_lib.topology_epoch(p, pre_stacked, post_stacked,
-                                                cfg, step=state.sample_idx)
-            fresh = tuple(DSSTAccumulator.init(a.pre.shape[0], a.post.shape[0])
-                          for a in accs)
-            return p2, fresh
+            def do(args):
+                p, accs = args
+                p2, _ = topology_lib.topology_epoch(
+                    p, pre_stacked, post_stacked, cfg, step=state.sample_idx)
+                fresh = tuple(DSSTAccumulator.init(a.pre.shape[0],
+                                                   a.post.shape[0])
+                              for a in accs)
+                return p2, fresh
 
-        def skip(args):
-            return args
+            def skip(args):
+                return args
 
-        new_params, new_acc = jax.lax.cond(
-            cfg.dsst.is_update_step(state.sample_idx), do, skip,
-            (new_params, new_acc))
+            new_params, new_acc = jax.lax.cond(
+                cfg.dsst.is_update_step(state.sample_idx), do, skip,
+                (new_params, new_acc))
 
     # ---- roll the CC slot: final trace of this sample becomes the negative ----
     final_layers = LayerState(

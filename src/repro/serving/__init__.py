@@ -14,7 +14,7 @@ from .checkpointing import restore_fleet, save_fleet
 from .ingest import IngestConfig, IngestWorker
 from .scheduler import StreamScheduler, TierConfig
 from .session import (SessionStatus, StreamSession, WindowPrediction,
-                      fresh_lane_state, read_lane, reset_lane, write_lane)
+                      fresh_lane_state, read_lane, write_lane)
 from .staging import InFlight, LaneRecord, StagedChunk, StagingPipeline
 from .stream_source import (AERStreamSource, ArrivalConfig, ReplaySource,
                             TaskStreamSource, aer_decode, aer_encode)
@@ -30,6 +30,6 @@ __all__ = [
     "StreamSession", "TaskStreamSource", "TierConfig", "TopologyEpochEvent",
     "TopologyService", "TopologyServiceConfig", "WindowPrediction",
     "aer_decode", "aer_encode", "delta_norms", "fresh_lane_state",
-    "make_chunk_fn", "merge_lane_into_base", "read_lane", "reset_lane",
+    "make_chunk_fn", "merge_lane_into_base", "read_lane",
     "restore_fleet", "save_fleet", "write_lane",
 ]

@@ -97,7 +97,7 @@ from .adapt import AdaptConfig, make_chunk_fn
 from .autopilot import AutopilotConfig, DepthAutopilot
 from .ingest import IngestConfig, IngestWorker
 from .session import (SessionStatus, StreamSession, WindowPrediction,
-                      reset_lane)
+                      fresh_lane_state, nbytes, write_lane)
 from .staging import InFlight, LaneRecord, StagedChunk, StagingPipeline
 from .telemetry import FleetTelemetry
 
@@ -177,11 +177,13 @@ class StreamScheduler:
         construction and after every topology swap.
       tracer: an ``obs.trace.Tracer`` recording phase-level spans
         (``sched.step/stage/poll_sources/admit/dispatch/retire/
-        device_wait``, ``topology.epoch``, ``autopilot.decision/apply``);
-        the shared no-op ``NULL_TRACER`` by default. Spans wrap host
-        phases at already-synchronous points only — tracing on vs. off is
-        bit-identical and leaves the serving jaxpr unchanged (pinned in
-        ``tests/test_obs_serving.py``).
+        device_wait``, ``topology.epoch``, ``autopilot.decision/apply``)
+        and their sub-spans (``admit.fresh_lane/write``,
+        ``dispatch.transfer/enqueue``, ``retire.deliver/telemetry/
+        snapshot``); the shared no-op ``NULL_TRACER`` by default. Spans
+        only time host work the scheduler does anyway — tracing on vs.
+        off is bit-identical and leaves the serving jaxpr unchanged
+        (pinned in ``tests/test_obs_serving.py``).
       tiers: optional QoS tier geometries (:class:`TierConfig` list,
         unique names). ``None`` = one tier named "default" built from
         ``n_slots``/``chunk_len`` — the exact pre-tier scheduler.
@@ -281,6 +283,10 @@ class StreamScheduler:
         self.chunk_len = self._tiers[0].chunk_len
         self._delta_sh = (sharding.slot_sharding(mesh)
                           if mesh is not None else None)
+        # the chunk fn's input shardings of events/valid/adapt_mask, for
+        # dispatch's explicit host->device put (None: the default device)
+        self._input_sh = (sharding.chunk_step_shardings(
+            mesh, want_factors)[0][3:] if mesh is not None else None)
 
         self.pipeline_depth = pipeline_depth
         self.clock = 0.0
@@ -388,18 +394,39 @@ class StreamScheduler:
         tier.state, tier.deltas = state, deltas
 
     def _admit(self, tier: _Tier) -> None:
-        with self.tracer.span("sched.admit", grid_step=self._staging_step,
+        """Claim free lanes for queued sessions, resetting each lane in
+        place (fresh one-lane state and zero delta written into the grid).
+
+        The ``sched.admit`` span counts what the lane writes cost:
+        ``leaves_written`` grid leaves and ``bytes_written``, the bytes
+        admission's eager programs output — each rewritten grid leaf whole
+        (``.at[slot].set`` returns a new array) plus the fresh lane. Both
+        are shape arithmetic, with no device sync."""
+        step = self._staging_step
+        with self.tracer.span("sched.admit", grid_step=step,
                               tier=tier.name) as sp:
-            n = 0
+            n, leaves, nbytes_out = 0, 0, 0
 
             def on_admit(slot: int, sess: StreamSession):
-                nonlocal n
+                nonlocal n, leaves, nbytes_out
                 n += 1
                 sess.slot, sess.status = slot, SessionStatus.ACTIVE
-                self._replace_lanes(tier, *reset_lane(
-                    tier.state, tier.deltas, self.cfg, slot))
+                with self.tracer.span("admit.fresh_lane", grid_step=step,
+                                      sid=sess.sid):
+                    s1, d1 = fresh_lane_state(self.cfg, compact=self.compact)
+                with self.tracer.span("admit.write", grid_step=step,
+                                      sid=sess.sid):
+                    self._replace_lanes(tier,
+                                        write_lane(tier.state, s1, slot),
+                                        write_lane(tier.deltas, d1, slot))
+                grid = jax.tree_util.tree_leaves((tier.state, tier.deltas))
+                leaves += len(grid)
+                nbytes_out += nbytes(grid) + nbytes((s1, d1))
             tier.grid.admit(on_admit)
-            sp.set(admitted=n)
+            sp.set(admitted=n, leaves_written=leaves,
+                   bytes_written=nbytes_out)
+        if n:
+            self.telemetry.record_admissions(n, nbytes_out)
 
     def _poll_sources(self) -> None:
         """Move newly arrived chunks into session buffers, fleet-wide.
@@ -494,24 +521,35 @@ class StreamScheduler:
 
     # -- phase 2: dispatch ---------------------------------------------------
     def _dispatch(self, tier: _Tier, staged: StagedChunk) -> InFlight:
-        """Enqueue the tier's chunk fn on the staged buffers —
-        asynchronous, no host wait — then free retiring sessions' lanes so
-        the *next* stage phase can re-admit into them (same admission
-        timing as the serial path, where retire frees lanes before the
-        next step's admits)."""
+        """Put the staged buffers on the device and enqueue the tier's
+        chunk fn on them — asynchronous, no host wait — then free retiring
+        sessions' lanes so the *next* stage phase can re-admit into them
+        (same admission timing as the serial path, where retire frees
+        lanes before the next step's admits).
+
+        The device copies of the staged buffers are locals: the chunk fn
+        holds them only until it has run, so they never outlive the step
+        (an ``InFlight`` keeps the host arrays, not these)."""
         t0 = time.perf_counter()
-        with self.tracer.span("sched.dispatch",
-                              grid_step=self._staging_step,
+        step = self._staging_step
+        host = (staged.events, staged.valid, staged.adapt_mask)
+        h2d = nbytes(host)
+        with self.tracer.span("sched.dispatch", grid_step=step,
                               tier=tier.name) as sp:
-            tier.deltas, tier.state, metrics = tier.chunk_fn(
-                self._exec_params, tier.deltas, tier.state, staged.events,
-                staged.valid, staged.adapt_mask)
+            with self.tracer.span("dispatch.transfer", grid_step=step):
+                events, valid, amask = jax.device_put(host, self._input_sh)
+            with self.tracer.span("dispatch.enqueue", grid_step=step):
+                tier.deltas, tier.state, metrics = tier.chunk_fn(
+                    self._exec_params, tier.deltas, tier.state, events,
+                    valid, amask)
             tier.grid.tick()
             for slot, _ in staged.retiring:
                 tier.grid.retire(slot)
-            sp.set(lanes=len(staged.lanes), retiring=len(staged.retiring))
+            sp.set(lanes=len(staged.lanes), retiring=len(staged.retiring),
+                   h2d_bytes=h2d)
             fl = InFlight(staged=staged, deltas=tier.deltas, metrics=metrics,
                           grid_step=tier.grid.stats["steps"])
+        self.telemetry.record_h2d(h2d)
         dt = time.perf_counter() - t0
         self.telemetry.record_phase("dispatch", dt)
         self.telemetry.record_tier_phase(tier.name, "dispatch", dt)
@@ -549,14 +587,52 @@ class StreamScheduler:
         self.telemetry.record_tier_phase(tier.name, "retire", dt)
 
     def _retire_body(self, tier: _Tier, fl: InFlight, m) -> None:
+        """Two passes over the step's lanes, then the retiring sessions:
+        deliver the window predictions first (a prediction lands as soon
+        as its step's results are on the host, not after the counter
+        fold), fold the chunk metrics into the per-stream and per-tier
+        counters, then snapshot retiring sessions' final deltas."""
         staged = fl.staged
-        logits = m.logits                      # [C, S, n_out]
+        with self.tracer.span("retire.deliver", grid_step=fl.grid_step):
+            logits = m.logits                  # [C, S, n_out]
+            wend = m.window_end                # [C, S]
+            for rec in staged.lanes:
+                slot, sess = rec.slot, rec.session
+                sess.timesteps_fed += rec.n_fed
+                for t in np.nonzero(wend[:, slot])[0]:
+                    sess.predictions.append(WindowPrediction(
+                        window_idx=len(sess.predictions),
+                        logits=logits[t, slot].copy()))
+        with self.tracer.span("retire.telemetry", grid_step=fl.grid_step):
+            self._fold_telemetry(tier, staged, m)
+        with self.tracer.span("retire.snapshot", grid_step=fl.grid_step,
+                              retiring=len(staged.retiring)) as sp:
+            for slot, sess in staged.retiring:
+                # the captured post-step handle, NOT tier.deltas: a later
+                # stage phase may already have re-admitted into this lane;
+                # layout is the fleet's: compact [L, J, T, bk, bo] or dense
+                # [L, Kmax, N]
+                sess.final_deltas = np.asarray(fl.deltas[slot])
+                sess.status, sess.slot = SessionStatus.RETIRED, None
+                if self.ingest is not None:
+                    self.ingest.detach(sess)
+                self.retired.append(sess)
+            sp.set(d2h_bytes=nbytes([sess.final_deltas
+                                     for _, sess in staged.retiring]))
+        svc = self.topology
+        if svc is not None and not svc.frozen and m.pre_mag is not None:
+            svc.observe(m)
+            self.maybe_evolve_topology(merge_slots=staged.merge_slots,
+                                       grid_step=fl.grid_step)
+
+    def _fold_telemetry(self, tier: _Tier, staged: StagedChunk, m) -> None:
+        """Fold each lane's slice of the chunk metrics into its stream's
+        counters, and the lanes' sums into the tier's."""
         wend = m.window_end                    # [C, S]
         tsum = {"steps": 0.0, "events_in": 0.0, "sop_forward": 0.0,
                 "sop_wu": 0.0, "sop_wu_offered": 0.0, "windows": 0}
         for rec in staged.lanes:
             slot, sess = rec.slot, rec.session
-            sess.timesteps_fed += rec.n_fed
             steps = float(m.steps[slot])
             sop_forward = float(m.sop_forward[slot])
             sop_wu = float(m.sop_wu[slot])
@@ -579,10 +655,6 @@ class StreamScheduler:
             tsum["sop_wu"] += sop_wu
             tsum["sop_wu_offered"] += sop_wu_offered
             tsum["windows"] += windows
-            for t in np.nonzero(wend[:, slot])[0]:
-                sess.predictions.append(WindowPrediction(
-                    window_idx=len(sess.predictions),
-                    logits=logits[t, slot].copy()))
         if staged.lanes:
             self.telemetry.record_tier_chunk(
                 tier.name, timesteps=tsum["steps"],
@@ -590,20 +662,6 @@ class StreamScheduler:
                 sop_forward=tsum["sop_forward"], sop_wu=tsum["sop_wu"],
                 sop_wu_offered=tsum["sop_wu_offered"],
                 windows=tsum["windows"])
-        for slot, sess in staged.retiring:
-            # the captured post-step handle, NOT tier.deltas: a later stage
-            # phase may already have re-admitted into this lane; layout is
-            # the fleet's: compact [L, J, T, bk, bo] or dense [L, Kmax, N]
-            sess.final_deltas = np.asarray(fl.deltas[slot])
-            sess.status, sess.slot = SessionStatus.RETIRED, None
-            if self.ingest is not None:
-                self.ingest.detach(sess)
-            self.retired.append(sess)
-        svc = self.topology
-        if svc is not None and not svc.frozen and m.pre_mag is not None:
-            svc.observe(m)
-            self.maybe_evolve_topology(merge_slots=staged.merge_slots,
-                                       grid_step=fl.grid_step)
 
     # -- adaptive depth ------------------------------------------------------
     def _apply_autopilot(self) -> None:

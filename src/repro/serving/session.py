@@ -8,10 +8,10 @@ thresholds, per-stream weight deltas) lives in batched pytrees whose leading
 axis is the slot index — sessions only remember *which lane* is theirs.
 
 Lane surgery (claiming a slot on admit, snapshotting on retire) is done with
-``write_lane`` / ``read_lane``: tree-maps over the batched pytrees that
-touch exactly one slot index, leaving every other stream's lane
-bit-identical. That single-lane discipline is what the isolation tests pin
-down.
+``fresh_lane_state`` + ``write_lane`` / ``read_lane``: tree-maps over the
+batched pytrees that touch exactly one slot index, leaving every other
+stream's lane bit-identical. That single-lane discipline is what the
+isolation tests pin down.
 """
 from __future__ import annotations
 
@@ -138,15 +138,14 @@ def read_lane(batched, slot: int):
 
 
 def fresh_lane_state(cfg: SNNConfig, compact: bool | None = None):
-    """A 1-slot initial ``(StreamState, deltas)`` pair used to reset a
-    claimed lane (``compact`` selects the delta layout; None = auto)."""
+    """A 1-slot initial ``(StreamState, deltas)`` pair that admission
+    writes into a claimed lane with :func:`write_lane` (fresh traces, zero
+    delta; ``compact`` selects the delta layout, None = auto)."""
     return init_stream_state(cfg, 1), init_stream_deltas(cfg, 1,
                                                          compact=compact)
 
 
-def reset_lane(state, deltas, cfg: SNNConfig, slot: int):
-    """Return ``(state, deltas)`` with lane ``slot`` re-initialized in
-    place (fresh traces, zero delta) — the admit-time lane surgery. The
-    fresh lane matches the layout of the ``deltas`` it is written into."""
-    s1, d1 = fresh_lane_state(cfg, compact=deltas.ndim == 6)
-    return write_lane(state, s1, slot), write_lane(deltas, d1, slot)
+def nbytes(tree) -> int:
+    """Summed ``nbytes`` of a pytree's array leaves: shape arithmetic, no
+    device sync (what the scheduler's byte counts add up)."""
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))

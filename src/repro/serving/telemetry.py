@@ -239,6 +239,21 @@ class FleetTelemetry:
             "serving_ingest_drained_chunks",
             "chunks released to session buffers per poll-window drain",
             buckets=QUEUE_DEPTH_BUCKETS)
+        # -- admission and host<->device traffic -----------------------------
+        self._admissions = self.registry.counter(
+            "serving_admissions_total", "sessions admitted into a lane")
+        self._admit_bytes = self.registry.counter(
+            "serving_admit_bytes_total",
+            "bytes output by admission's lane writes: every rewritten grid "
+            "leaf whole, plus the fresh lane")
+        self._h2d_bytes = self.registry.counter(
+            "serving_h2d_bytes_total",
+            "bytes of the staged events/valid/adapt-mask buffers put on the "
+            "device at dispatch")
+        self._stream_series = self.registry.gauge(
+            "serving_stream_series",
+            "live per-stream counter records (one per sid served; a retired "
+            "sid's series is never dropped)")
         # recent-events ring: the per-epoch *log* is bounded (a long-lived
         # fleet otherwise grows it forever — the lint's OBS01 class), while
         # the exact aggregates live in the registry counters above and
@@ -257,6 +272,7 @@ class FleetTelemetry:
         with self._lock:
             if sid not in self.streams:
                 self.streams[sid] = StreamCounters(sid, self.registry)
+                self._stream_series.set(float(len(self.streams)))
             return self.streams[sid]
 
     def record_step(self, latency_s: float) -> None:
@@ -344,6 +360,17 @@ class FleetTelemetry:
         self._ingest_chunks.inc(int(chunks))
         self._ingest_queue_peak.set(float(queue_peak))
         self._ingest_drain_hist.observe(float(chunks))
+
+    def record_admissions(self, sessions: int, bytes_written: int) -> None:
+        """Log one admission pass: sessions admitted and the bytes its lane
+        writes output (shape arithmetic at the call site — no device
+        sync)."""
+        self._admissions.inc(int(sessions))
+        self._admit_bytes.inc(int(bytes_written))
+
+    def record_h2d(self, nbytes: int) -> None:
+        """Log the bytes one dispatch put on the device."""
+        self._h2d_bytes.inc(int(nbytes))
 
     def record_bytes_held(self, params_bytes: int, delta_bytes: int) -> None:
         """Log the resident serving weight-state bytes (scheduler-measured

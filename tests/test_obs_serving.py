@@ -290,6 +290,182 @@ def test_depth2_tracing_parity_and_spans(params, frozen_runs):
         assert got == list(range(1, steps + 1)), (name, got)
 
 
+# ------------------------------------- host sub-spans and their byte counts
+
+def _nbytes(tree):
+    return sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def test_sub_spans_nest_under_their_phase(frozen_runs):
+    """Admission, dispatch and retire split into named children: one
+    ``admit.fresh_lane`` and one ``admit.write`` per admitted session,
+    one transfer and one enqueue per dispatch, one deliver, telemetry and
+    snapshot per retire — each the child of its phase's span, and
+    delivery before the counter fold."""
+    sched, _, _ = frozen_runs[1]
+    tr = sched.tracer
+    by_id = {s.span_id: s for s in tr.spans()}
+    parents = {"admit.fresh_lane": "sched.admit",
+               "admit.write": "sched.admit",
+               "dispatch.transfer": "sched.dispatch",
+               "dispatch.enqueue": "sched.dispatch",
+               "retire.deliver": "sched.retire",
+               "retire.telemetry": "sched.retire",
+               "retire.snapshot": "sched.retire"}
+    for name, parent in parents.items():
+        spans = tr.spans(name)
+        assert spans, name
+        for s in spans:
+            assert by_id[s.parent_id].name == parent, (name, s)
+    admitted = sum(s.attr("admitted") for s in tr.spans("sched.admit"))
+    assert admitted == 5                       # every stream, once
+    assert len(tr.spans("admit.fresh_lane")) == admitted
+    assert len(tr.spans("admit.write")) == admitted
+    steps = sched.grid.stats["steps"]
+    for name in ("dispatch.transfer", "dispatch.enqueue", "retire.deliver",
+                 "retire.telemetry", "retire.snapshot"):
+        got = sorted(s.attr("grid_step") for s in tr.spans(name))
+        assert got == list(range(1, steps + 1)), (name, got)
+    for retire in tr.spans("sched.retire"):
+        kids = {s.name: s for s in tr.spans()
+                if s.parent_id == retire.span_id}
+        assert kids["retire.deliver"].t0_s + kids["retire.deliver"].dur_s \
+            <= kids["retire.telemetry"].t0_s
+        assert kids["retire.telemetry"].t0_s < kids["retire.snapshot"].t0_s
+
+
+def test_admit_dispatch_and_snapshot_byte_counts(frozen_runs):
+    """``bytes_written`` is every rewritten grid leaf whole plus the fresh
+    lane, per admitted session; ``h2d_bytes`` is the staged buffers;
+    ``d2h_bytes`` the retiring sessions' final deltas — and the registry
+    counters add up the same numbers."""
+    sched, _, done = frozen_runs[1]
+    tr = sched.tracer
+    S, C = sched.n_slots, sched.chunk_len
+    grid = (init_stream_state(CFG, S), init_stream_deltas(CFG, S))
+    lane = (init_stream_state(CFG, 1), init_stream_deltas(CFG, 1))
+    per_session = _nbytes(grid) + _nbytes(lane)
+    n_leaves = len(jax.tree_util.tree_leaves(grid))
+    admits = tr.spans("sched.admit")
+    for s in admits:
+        n = s.attr("admitted")
+        assert s.attr("bytes_written") == n * per_session, s
+        assert s.attr("leaves_written") == n * n_leaves, s
+    h2d = C * S * CFG.n_in * 4 + C * S + S      # f32 events, bool valid/mask
+    dispatches = tr.spans("sched.dispatch")
+    assert {s.attr("h2d_bytes") for s in dispatches} == {h2d}
+    lane_delta = _nbytes(init_stream_deltas(CFG, 1))
+    snaps = tr.spans("retire.snapshot")
+    assert sum(s.attr("retiring") for s in snaps) == len(done) == 5
+    for s in snaps:
+        assert s.attr("d2h_bytes") == s.attr("retiring") * lane_delta, s
+    scrape = parse_prometheus_text(prometheus_text(sched.telemetry.registry))
+    assert scrape["serving_admissions_total"] == 5
+    assert scrape["serving_admit_bytes_total"] == sum(
+        s.attr("bytes_written") for s in admits) == 5 * per_session
+    assert scrape["serving_h2d_bytes_total"] == len(dispatches) * h2d
+    assert scrape["serving_stream_series"] == len(sched.telemetry.streams) \
+        == 5
+
+
+def test_tracing_8device_bit_identical_with_readmission(params):
+    """Tracer on == tracer off on the 8-device slot-sharded pipelined grid
+    with more sessions than lanes, so lanes are re-admitted under the
+    mesh; the explicit dispatch transfer uses the chunk fn's input
+    shardings (no recompile) and its spans and byte counts are there."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    code = textwrap.dedent("""
+        import numpy as np, jax
+        from repro.core.snn import SNNConfig, init_params
+        from repro.launch.mesh import make_serving_mesh
+        from repro.obs import Tracer
+        from repro.serving import ReplaySource, StreamScheduler, StreamSession
+
+        cfg = SNNConfig(n_in=32, n_hidden=32, n_layers=2, n_out=8, t_steps=8)
+        params = init_params(jax.random.PRNGKey(0), cfg)
+
+        def events(seed, t, rate=0.3):
+            r = np.random.default_rng(seed)
+            return (r.random((t, cfg.n_in)) < rate).astype(np.float32)
+
+        def drive(tracer):
+            sched = StreamScheduler(params, cfg, n_slots=16, chunk_len=4,
+                                    mesh=make_serving_mesh(),
+                                    pipeline_depth=1, tracer=tracer)
+            for sid in range(40):
+                sched.submit(StreamSession(
+                    sid=sid,
+                    source=ReplaySource(
+                        events(sid, (1 + sid % 2) * cfg.t_steps)),
+                    adapt=(sid % 2 == 0)))
+            return sched, {s.sid: s for s in sched.run_until_drained()}
+
+        tr = Tracer(capacity=65536)
+        s0, d0 = drive(None)
+        s1, d1 = drive(tr)
+        assert s0.n_compiles == 1 and s1.n_compiles == 1
+        assert len(d0) == len(d1) == 40
+        for sid in d0:
+            assert len(d0[sid].predictions) == len(d1[sid].predictions) > 0
+            for a, b in zip(d0[sid].predictions, d1[sid].predictions):
+                np.testing.assert_array_equal(a.logits, b.logits)
+            np.testing.assert_array_equal(d0[sid].final_deltas,
+                                          d1[sid].final_deltas)
+        steps = s1.grid.stats["steps"]
+        assert len(tr.spans("dispatch.transfer")) == steps > 0
+        assert len(tr.spans("admit.write")) == 40
+        S = s1.n_slots
+        assert {s.attr("h2d_bytes") for s in tr.spans("sched.dispatch")} \
+            == {4 * S * cfg.n_in * 4 + 4 * S + S}
+        assert s1.deltas.sharding.is_equivalent_to(s0.deltas.sharding, 6)
+        print("OK")
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=560)
+    assert out.returncode == 0, out.stdout + "\n" + out.stderr
+
+
+# ------------------------------------------------ named device scopes
+
+CHUNK_SCOPES = ("si_base", "si_delta", "lif", "ossl", "gate", "wu_delta",
+                "telemetry", "readout", "window_roll")
+TRAIN_SCOPES = ("si_base", "lif", "ossl", "gate", "wu_base", "telemetry",
+                "readout", "dsst")
+
+
+def _scopes_in(hlo_text):
+    import re
+    names = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', hlo_text):
+        names.update(op_name.split("/"))
+    return names
+
+
+def test_named_scopes_in_compiled_hlo(params):
+    """The engine's stages carry their names into the compiled HLO's op
+    metadata — what a device profile reads per op — in the serving chunk
+    program (``jit_chunk_fn``) and the training step (``jit_step``)."""
+    from repro.core.snn import init_state, make_train_fn
+    sched = StreamScheduler(params, CFG, n_slots=3, chunk_len=6)
+    args = (sched._exec_params, sched.deltas, sched.state,
+            np.zeros((6, 3, CFG.n_in), np.float32), np.ones((6, 3), bool),
+            np.ones(3, bool))
+    hlo = sched.chunk_fn.lower(*args).compile().as_text()
+    assert "HloModule jit_chunk_fn" in hlo
+    assert set(CHUNK_SCOPES) <= _scopes_in(hlo), \
+        set(CHUNK_SCOPES) - _scopes_in(hlo)
+    step = make_train_fn(CFG)
+    hlo = step.lower(params, init_state(CFG, 4),
+                     np.zeros((CFG.t_steps, 4, CFG.n_in), np.float32),
+                     np.zeros(4, np.int32)).compile().as_text()
+    assert "HloModule jit_step" in hlo
+    assert set(TRAIN_SCOPES) <= _scopes_in(hlo), \
+        set(TRAIN_SCOPES) - _scopes_in(hlo)
+
+
 # ------------------------------------------------- telemetry regressions
 
 def test_fleet_telemetry_memory_is_bounded():
