@@ -66,8 +66,8 @@ With a ``("slots",)`` mesh (``launch.mesh.make_serving_mesh``) the grid
 shards over devices: each tier's slot allocation pads to the device
 count (``launch.sharding.tier_slot_allocation``), the chunk step runs
 under slot-axis ``shard_map`` (bit-identical to 1-device — see
-serving/adapt.py), and lane surgery re-places its result so the slot
-sharding survives admit/retire.
+serving/adapt.py), and the admission reset program takes and returns the
+slot shardings, so they survive admit/retire.
 
 With a ``TopologyService`` attached (single-tier fleets only — an epoch
 folds the whole fleet's deltas into one base), the chunk fn is built
@@ -97,7 +97,7 @@ from .adapt import AdaptConfig, make_chunk_fn
 from .autopilot import AutopilotConfig, DepthAutopilot
 from .ingest import IngestConfig, IngestWorker
 from .session import (SessionStatus, StreamSession, WindowPrediction,
-                      fresh_lane_state, nbytes, write_lane)
+                      make_reset_lanes, nbytes)
 from .staging import InFlight, LaneRecord, StagedChunk, StagingPipeline
 from .telemetry import FleetTelemetry
 
@@ -127,12 +127,13 @@ class TierConfig:
 
 class _Tier:
     """Runtime state of one tier: its slot grid, lane-batched device
-    state/deltas (+ shardings), compiled chunk fn, and staging pipeline.
+    state/deltas (+ shardings), compiled chunk fn and lane-reset program,
+    and staging pipeline.
     ``slot0`` is the tier's offset in the fleet-global slot numbering
     (``step()`` returns global slot ids; everything internal is local)."""
 
     __slots__ = ("name", "chunk_len", "n_slots", "slot0", "grid", "state",
-                 "deltas", "chunk_fn", "pipeline", "state_sh")
+                 "deltas", "chunk_fn", "reset_lanes", "pipeline", "state_sh")
 
     def __init__(self, name: str, chunk_len: int, n_slots: int, slot0: int):
         self.name, self.chunk_len = name, chunk_len
@@ -178,9 +179,9 @@ class StreamScheduler:
       tracer: an ``obs.trace.Tracer`` recording phase-level spans
         (``sched.step/stage/poll_sources/admit/dispatch/retire/
         device_wait``, ``topology.epoch``, ``autopilot.decision/apply``)
-        and their sub-spans (``admit.fresh_lane/write``,
-        ``dispatch.transfer/enqueue``, ``retire.deliver/telemetry/
-        snapshot``); the shared no-op ``NULL_TRACER`` by default. Spans
+        and their sub-spans (``admit.write``, ``dispatch.transfer/
+        enqueue``, ``retire.deliver/telemetry/snapshot``); the shared
+        no-op ``NULL_TRACER`` by default. Spans
         only time host work the scheduler does anyway — tracing on vs.
         off is bit-identical and leaves the serving jaxpr unchanged
         (pinned in ``tests/test_obs_serving.py``).
@@ -259,6 +260,8 @@ class StreamScheduler:
             tier_cfgs = [dataclasses.replace(t, n_slots=w)
                          for t, w in zip(tier_cfgs, widths)]
 
+        self._delta_sh = (sharding.slot_sharding(mesh)
+                          if mesh is not None else None)
         self._tiers: List[_Tier] = []
         slot0 = 0
         for tc in tier_cfgs:
@@ -270,19 +273,20 @@ class StreamScheduler:
             if mesh is not None:
                 tier.state_sh = sharding.stream_shardings(tier.state, mesh)
                 tier.state = jax.device_put(tier.state, tier.state_sh)
-                tier.deltas = jax.device_put(tier.deltas,
-                                             sharding.slot_sharding(mesh))
-            # one compiled chunk fn per tier (its own [C, S] static shape
-            # and its own trace counter); all tiers share cfg/adapt/exec rep
+                tier.deltas = jax.device_put(tier.deltas, self._delta_sh)
+            # one compiled chunk fn and lane reset per tier (their own [S]
+            # static shapes and trace counters); all tiers share cfg/adapt/
+            # exec rep
             tier.chunk_fn = make_chunk_fn(cfg, adapt, mesh=mesh,
                                           want_factors=want_factors)
+            tier.reset_lanes = make_reset_lanes(
+                cfg, compact, state_sh=tier.state_sh,
+                delta_sh=self._delta_sh)
             tier.pipeline = StagingPipeline(depth=pipeline_depth)
             self._tiers.append(tier)
         self._by_name = {t.name: t for t in self._tiers}
         self.n_slots = slot0                    # fleet-wide lane count
         self.chunk_len = self._tiers[0].chunk_len
-        self._delta_sh = (sharding.slot_sharding(mesh)
-                          if mesh is not None else None)
         # the chunk fn's input shardings of events/valid/adapt_mask, for
         # dispatch's explicit host->device put (None: the default device)
         self._input_sh = (sharding.chunk_step_shardings(
@@ -384,46 +388,51 @@ class StreamScheduler:
             self.ingest.stop()
 
     def _replace_lanes(self, tier: _Tier, state, deltas) -> None:
-        """Install post-surgery state/deltas on ``tier``, restoring the
-        slot sharding — eager ``.at[slot].set`` lane writes are
-        single-lane-correct on sharded arrays but may leave the result
-        unplaced."""
+        """Install state/deltas made outside the scheduler (a topology
+        epoch's) on ``tier``, restoring the slot sharding under a mesh."""
         if self.mesh is not None:
             state = jax.device_put(state, tier.state_sh)
             deltas = jax.device_put(deltas, self._delta_sh)
         tier.state, tier.deltas = state, deltas
 
     def _admit(self, tier: _Tier) -> None:
-        """Claim free lanes for queued sessions, resetting each lane in
-        place (fresh one-lane state and zero delta written into the grid).
+        """Claim free lanes for queued sessions and reset them all in
+        place with one call of the tier's lane-reset program (the grids
+        are donated to it; no program runs when nobody is admitted).
 
-        The ``sched.admit`` span counts what the lane writes cost:
-        ``leaves_written`` grid leaves and ``bytes_written``, the bytes
-        admission's eager programs output — each rewritten grid leaf whole
-        (``.at[slot].set`` returns a new array) plus the fresh lane. Both
-        are shape arithmetic, with no device sync."""
+        A donated delta grid may still be an in-flight step's captured
+        handle — at depth >= 1 this stage re-admits lanes that step's
+        dispatch freed before its retire has snapshotted them — so those
+        steps' retiring lanes are sliced off it first.
+
+        The ``sched.admit`` span counts ``admitted`` sessions, the
+        ``programs`` run (0 or 1), the grid ``leaves_written`` by that
+        call, and ``bytes_written``: the fresh lanes the program writes
+        into the grid, one lane's bytes per admitted session. All shape
+        arithmetic, with no device sync; its ``admit.write`` child times
+        the program's enqueue."""
         step = self._staging_step
         with self.tracer.span("sched.admit", grid_step=step,
                               tier=tier.name) as sp:
-            n, leaves, nbytes_out = 0, 0, 0
-
-            def on_admit(slot: int, sess: StreamSession):
-                nonlocal n, leaves, nbytes_out
-                n += 1
-                sess.slot, sess.status = slot, SessionStatus.ACTIVE
-                with self.tracer.span("admit.fresh_lane", grid_step=step,
-                                      sid=sess.sid):
-                    s1, d1 = fresh_lane_state(self.cfg, compact=self.compact)
+            admitted = tier.grid.admit()
+            n = len(admitted)
+            programs = leaves = nbytes_out = 0
+            if n:
+                for slot, sess in admitted:
+                    sess.slot, sess.status = slot, SessionStatus.ACTIVE
+                for fl in tier.pipeline:
+                    if fl.deltas is tier.deltas:
+                        fl.take_snapshots()
+                slots = np.full(tier.n_slots, tier.n_slots, np.int32)
+                slots[:n] = [slot for slot, _ in admitted]
                 with self.tracer.span("admit.write", grid_step=step,
-                                      sid=sess.sid):
-                    self._replace_lanes(tier,
-                                        write_lane(tier.state, s1, slot),
-                                        write_lane(tier.deltas, d1, slot))
+                                      admitted=n):
+                    tier.state, tier.deltas = tier.reset_lanes(
+                        tier.state, tier.deltas, slots)
                 grid = jax.tree_util.tree_leaves((tier.state, tier.deltas))
-                leaves += len(grid)
-                nbytes_out += nbytes(grid) + nbytes((s1, d1))
-            tier.grid.admit(on_admit)
-            sp.set(admitted=n, leaves_written=leaves,
+                programs, leaves = 1, len(grid)
+                nbytes_out = n * (nbytes(grid) // tier.n_slots)
+            sp.set(admitted=n, programs=programs, leaves_written=leaves,
                    bytes_written=nbytes_out)
         if n:
             self.telemetry.record_admissions(n, nbytes_out)
@@ -461,8 +470,8 @@ class StreamScheduler:
 
     # -- phase 1: stage ------------------------------------------------------
     def _stage(self, tier: _Tier) -> StagedChunk:
-        """Host-only assembly of one tier's grid step (no device
-        interaction).
+        """Host assembly of one tier's grid step; its only device work is
+        admission's asynchronous lane-reset enqueue (no device wait).
 
         Advances the clock and drains/polls sources (first tier only —
         both are fleet-wide facts), admits into the tier's free lanes,
@@ -607,12 +616,14 @@ class StreamScheduler:
             self._fold_telemetry(tier, staged, m)
         with self.tracer.span("retire.snapshot", grid_step=fl.grid_step,
                               retiring=len(staged.retiring)) as sp:
+            # slices of the captured post-step grid, NOT tier.deltas: a
+            # later stage phase may already have re-admitted into these
+            # lanes (and then took the slices before donating the grid)
+            fl.take_snapshots()
             for slot, sess in staged.retiring:
-                # the captured post-step handle, NOT tier.deltas: a later
-                # stage phase may already have re-admitted into this lane;
-                # layout is the fleet's: compact [L, J, T, bk, bo] or dense
-                # [L, Kmax, N]
-                sess.final_deltas = np.asarray(fl.deltas[slot])
+                # layout is the fleet's: compact [L, J, T, bk, bo] or
+                # dense [L, Kmax, N]
+                sess.final_deltas = np.asarray(fl.snapshots[slot])
                 sess.status, sess.slot = SessionStatus.RETIRED, None
                 if self.ingest is not None:
                     self.ingest.detach(sess)

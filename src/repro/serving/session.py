@@ -7,22 +7,25 @@ state (membrane potentials, the three-trace neuron SRAM, per-stream gate
 thresholds, per-stream weight deltas) lives in batched pytrees whose leading
 axis is the slot index — sessions only remember *which lane* is theirs.
 
-Lane surgery (claiming a slot on admit, snapshotting on retire) is done with
-``fresh_lane_state`` + ``write_lane`` / ``read_lane``: tree-maps over the
-batched pytrees that touch exactly one slot index, leaving every other
-stream's lane bit-identical. That single-lane discipline is what the
-isolation tests pin down.
+Lane surgery touches exactly the lanes it names and leaves every other
+stream's lane bit-identical. Admission resets all of a stage's claimed
+lanes at once with the program :func:`make_reset_lanes` builds: one
+donated, in-place write of fresh values into those lanes. ``write_lane`` /
+``read_lane`` / ``fresh_lane_state`` are the single-lane reference
+(tree-maps over one slot index) that the isolation tests pin it against.
 """
 from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 from typing import Any, List, Optional
 
 import jax
 import numpy as np
 
 from repro.core.snn import (SNNConfig, init_stream_deltas, init_stream_state)
+from repro.launch import sharding
 
 
 class SessionStatus(enum.Enum):
@@ -138,11 +141,55 @@ def read_lane(batched, slot: int):
 
 
 def fresh_lane_state(cfg: SNNConfig, compact: bool | None = None):
-    """A 1-slot initial ``(StreamState, deltas)`` pair that admission
-    writes into a claimed lane with :func:`write_lane` (fresh traces, zero
-    delta; ``compact`` selects the delta layout, None = auto)."""
+    """A 1-slot initial ``(StreamState, deltas)`` pair: what admission
+    resets a claimed lane to (fresh traces, zero delta; ``compact``
+    selects the delta layout, None = auto)."""
     return init_stream_state(cfg, 1), init_stream_deltas(cfg, 1,
                                                          compact=compact)
+
+
+def make_reset_lanes(cfg: SNNConfig, compact: bool | None = None,
+                     state_sh=None, delta_sh=None):
+    """Build ``reset_lanes(state, deltas, slots) -> (state, deltas)``: one
+    jitted program that resets every lane named in ``slots`` to its
+    initial value, in place.
+
+    ``state``/``deltas`` are the slot-leading grids and are donated: the
+    caller must drop every other handle to them first. ``slots`` is a
+    fixed-length ``[S]`` int32 vector, the admitted lanes first and then
+    ``S`` (out of range) as padding, so one compile serves any number of
+    admitted lanes. The program loops over the admitted lanes and writes
+    each one's fresh value over it, touching no other lane and holding no
+    second copy of the grid. The fresh values are
+    :func:`fresh_lane_state`'s, built inside the program: nothing is sent
+    from the host and no lane is allocated per session.
+    ``state_sh``/``delta_sh``, the grids' slot shardings under a mesh,
+    become the program's in and out shardings, so the reset keeps them
+    (each device writes only the admitted lanes it holds, with no
+    collective). ``reset_lanes.n_traces()`` counts its traces.
+    """
+    traces = {"n": 0}
+    jit_kw = {}
+    if state_sh is not None:
+        rep = sharding.replicated(delta_sh.mesh)
+        jit_kw = {"in_shardings": (state_sh, delta_sh, rep),
+                  "out_shardings": (state_sh, delta_sh)}
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1), **jit_kw)
+    def reset_lanes(state, deltas, slots):
+        traces["n"] += 1
+        fresh = fresh_lane_state(cfg, compact=compact)
+
+        def reset_one(i, grid):
+            return jax.tree_util.tree_map(
+                lambda b, f: jax.lax.dynamic_update_slice_in_dim(
+                    b, f, slots[i], 0), grid, fresh)
+
+        admitted = (slots < slots.shape[0]).sum()
+        return jax.lax.fori_loop(0, admitted, reset_one, (state, deltas))
+
+    reset_lanes.n_traces = lambda: traces["n"]
+    return reset_lanes
 
 
 def nbytes(tree) -> int:

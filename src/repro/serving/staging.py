@@ -7,8 +7,9 @@ ran.  This module is the pipelining half of the fix (the other half is the
 static ``want_factors`` seam in ``adapt.make_chunk_fn``): the scheduler's
 step is split into three explicit phases —
 
-* **stage**   — host-only: advance the virtual clock, poll sources, admit
-  queued sessions, pack the ``[C, S, n_in]`` event / ``[C, S]`` valid
+* **stage**   — host work: advance the virtual clock, poll sources, admit
+  queued sessions (enqueueing one lane-reset program, never waiting on
+  the device), pack the ``[C, S, n_in]`` event / ``[C, S]`` valid
   buffers, and *decide* which sessions will exhaust after this step (a
   pure host fact: source done + pending buffer drained).  Produces a
   :class:`StagedChunk`.
@@ -25,12 +26,14 @@ With ``depth=0`` the three phases run back-to-back inside one ``step()``
 (:class:`StagingPipeline` holds the in-flight steps) the stage phase for
 grid step ``t+1`` runs **while the device computes step t**, exactly the
 way event-driven silicon (ElfCore's async SerDes front-end, ReckOn's
-spike buffers) hides I/O behind compute.  Because JAX arrays are
-immutable, the in-flight record's ``deltas``/``metrics`` handles are
-unaffected by the lane surgery later stages perform on the scheduler's
-live arrays, so deferred bookkeeping reads exactly the values the step
-produced — the pipeline changes *when* host work happens, never *what*
-the device computes.  Pipeline-on and pipeline-off trajectories are
+spike buffers) hides I/O behind compute.  The in-flight record's
+``metrics`` handles are never touched by later stages; admission's lane
+reset donates the live delta grid, so before it runs the scheduler moves
+the retiring lanes' final deltas of any in-flight step that still holds
+that grid into per-lane slices (``InFlight.take_snapshots``).  Deferred
+bookkeeping therefore reads exactly the values the step produced — the
+pipeline changes *when* host work happens, never *what* the device
+computes.  Pipeline-on and pipeline-off trajectories are
 pinned bit-identical (1-device and 8-device) in
 ``tests/test_serving_pipeline.py``.
 """
@@ -39,7 +42,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Tuple
+from typing import Any, Deque, Dict, Iterator, List, Optional, Tuple
 
 
 @dataclasses.dataclass
@@ -79,15 +82,31 @@ class InFlight:
     """A dispatched-but-unretired grid step: the staged host record plus
     the chunk fn's (asynchronous) output handles.  ``deltas`` is captured
     at dispatch, so retiring sessions snapshot their final adaptation even
-    if a later admit has already reset that lane on the live arrays."""
+    if a later admit has already reset that lane on the live arrays.
+
+    Admission's reset program donates the live delta grid, which may be
+    this very handle: before it runs, :meth:`take_snapshots` slices the
+    retiring lanes off the grid into ``snapshots`` and drops ``deltas``
+    (retire does the same, for a step whose grid was never donated)."""
     staged: StagedChunk
-    deltas: Any                  # slot-leading delta handle (post-step); compact [S, L, J, T, bk, bo] or dense [S, L, Kmax, N]
+    deltas: Any                  # slot-leading delta handle (post-step); compact [S, L, J, T, bk, bo] or dense [S, L, Kmax, N]; None once snapshotted
     metrics: Any                 # ChunkMetrics device handles
     grid_step: int               # grid.stats["steps"] after this step's tick
+    # {slot: final-delta device handle} of the staged retiring lanes
+    snapshots: Optional[Dict[int, Any]] = None
     # host/device overlap bookkeeping (stamped by StagingPipeline push/pop;
     # both stay 0.0 on the serial depth=0 path, which never enqueues)
     pushed_at: float = 0.0       # perf_counter when the step entered the queue
     queued_s: float = 0.0        # time in flight before retire began
+
+    def take_snapshots(self) -> None:
+        """Slice each retiring lane's final deltas off the captured grid
+        (eager device slices, no host wait) and drop the grid handle;
+        idempotent."""
+        if self.snapshots is None:
+            self.snapshots = {slot: self.deltas[slot]
+                              for slot, _ in self.staged.retiring}
+            self.deltas = None
 
 
 class StagingPipeline:
@@ -132,6 +151,10 @@ class StagingPipeline:
 
     def __len__(self) -> int:
         return len(self._q)
+
+    def __iter__(self) -> Iterator[InFlight]:
+        """The in-flight steps, oldest first."""
+        return iter(self._q)
 
     @property
     def full(self) -> bool:

@@ -298,15 +298,14 @@ def _nbytes(tree):
 
 def test_sub_spans_nest_under_their_phase(frozen_runs):
     """Admission, dispatch and retire split into named children: one
-    ``admit.fresh_lane`` and one ``admit.write`` per admitted session,
-    one transfer and one enqueue per dispatch, one deliver, telemetry and
-    snapshot per retire — each the child of its phase's span, and
-    delivery before the counter fold."""
+    ``admit.write`` (the lane-reset program's enqueue) per admission that
+    admitted anyone, one transfer and one enqueue per dispatch, one
+    deliver, telemetry and snapshot per retire — each the child of its
+    phase's span, and delivery before the counter fold."""
     sched, _, _ = frozen_runs[1]
     tr = sched.tracer
     by_id = {s.span_id: s for s in tr.spans()}
-    parents = {"admit.fresh_lane": "sched.admit",
-               "admit.write": "sched.admit",
+    parents = {"admit.write": "sched.admit",
                "dispatch.transfer": "sched.dispatch",
                "dispatch.enqueue": "sched.dispatch",
                "retire.deliver": "sched.retire",
@@ -317,10 +316,16 @@ def test_sub_spans_nest_under_their_phase(frozen_runs):
         assert spans, name
         for s in spans:
             assert by_id[s.parent_id].name == parent, (name, s)
-    admitted = sum(s.attr("admitted") for s in tr.spans("sched.admit"))
-    assert admitted == 5                       # every stream, once
-    assert len(tr.spans("admit.fresh_lane")) == admitted
-    assert len(tr.spans("admit.write")) == admitted
+    admits = tr.spans("sched.admit")
+    assert sum(s.attr("admitted") for s in admits) == 5   # every stream
+    busy = [s for s in admits if s.attr("admitted")]
+    assert 1 < len(busy) < 5                   # lanes re-admitted, batched
+    assert tr.spans("admit.fresh_lane") == []  # no host-built lane
+    writes = tr.spans("admit.write")
+    assert sorted(w.parent_id for w in writes) \
+        == sorted(s.span_id for s in busy)
+    assert [w.attr("admitted") for w in writes] \
+        == [s.attr("admitted") for s in busy]
     steps = sched.grid.stats["steps"]
     for name in ("dispatch.transfer", "dispatch.enqueue", "retire.deliver",
                  "retire.telemetry", "retire.snapshot"):
@@ -335,22 +340,24 @@ def test_sub_spans_nest_under_their_phase(frozen_runs):
 
 
 def test_admit_dispatch_and_snapshot_byte_counts(frozen_runs):
-    """``bytes_written`` is every rewritten grid leaf whole plus the fresh
-    lane, per admitted session; ``h2d_bytes`` is the staged buffers;
-    ``d2h_bytes`` the retiring sessions' final deltas — and the registry
-    counters add up the same numbers."""
+    """``bytes_written`` is one fresh lane per admitted session (what the
+    reset program scatters into the grid), ``leaves_written`` the grid
+    leaves of its one call and ``programs`` 0 or 1; ``h2d_bytes`` is the
+    staged buffers; ``d2h_bytes`` the retiring sessions' final deltas —
+    and the registry counters add up the same numbers."""
     sched, _, done = frozen_runs[1]
     tr = sched.tracer
     S, C = sched.n_slots, sched.chunk_len
-    grid = (init_stream_state(CFG, S), init_stream_deltas(CFG, S))
     lane = (init_stream_state(CFG, 1), init_stream_deltas(CFG, 1))
-    per_session = _nbytes(grid) + _nbytes(lane)
-    n_leaves = len(jax.tree_util.tree_leaves(grid))
+    per_session = _nbytes(lane)
+    n_leaves = len(jax.tree_util.tree_leaves(lane))
     admits = tr.spans("sched.admit")
+    assert {s.attr("programs") for s in admits} == {0, 1}
     for s in admits:
         n = s.attr("admitted")
+        assert s.attr("programs") == int(n > 0), s
         assert s.attr("bytes_written") == n * per_session, s
-        assert s.attr("leaves_written") == n * n_leaves, s
+        assert s.attr("leaves_written") == (n_leaves if n else 0), s
     h2d = C * S * CFG.n_in * 4 + C * S + S      # f32 events, bool valid/mask
     dispatches = tr.spans("sched.dispatch")
     assert {s.attr("h2d_bytes") for s in dispatches} == {h2d}
@@ -416,7 +423,9 @@ def test_tracing_8device_bit_identical_with_readmission(params):
                                           d1[sid].final_deltas)
         steps = s1.grid.stats["steps"]
         assert len(tr.spans("dispatch.transfer")) == steps > 0
-        assert len(tr.spans("admit.write")) == 40
+        busy = [s for s in tr.spans("sched.admit") if s.attr("admitted")]
+        assert sum(s.attr("admitted") for s in busy) == 40
+        assert len(tr.spans("admit.write")) == len(busy) < 40
         S = s1.n_slots
         assert {s.attr("h2d_bytes") for s in tr.spans("sched.dispatch")} \
             == {4 * S * cfg.n_in * 4 + 4 * S + S}

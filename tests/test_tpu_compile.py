@@ -145,6 +145,35 @@ def test_sharded_chunk_fn_has_no_collectives(topo):
     assert not COLLECTIVE.search(text), COLLECTIVE.findall(text)[:5]
 
 
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "mesh4"])
+def test_lane_reset_is_in_place(topo, chips):
+    """Admission's lane reset at the smoke's grid: the donated grids are
+    the outputs (aliased), the program holds no second copy of them, and
+    on the 4-chip slot mesh it needs no collective."""
+    from repro.launch import sharding
+    from repro.serving.session import make_reset_lanes
+    mesh = Mesh(np.asarray(topo.devices[:chips]), ("slots",))
+    st = jax.eval_shape(lambda: snn.init_stream_state(CONFIG, N_SLOTS))
+    dl = jax.eval_shape(lambda: snn.init_stream_deltas(CONFIG, N_SLOTS))
+    st_sh = sharding.stream_shardings(st, mesh)
+    dl_sh = sharding.slot_sharding(mesh)
+    fn = make_reset_lanes(CONFIG, True, st_sh, dl_sh)
+    compiled = fn.lower(
+        jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            st, st_sh),
+        _shapes(dl, dl_sh),
+        jax.ShapeDtypeStruct((N_SLOTS,), jnp.int32,
+                             sharding=sharding.replicated(mesh))).compile()
+    grid = sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves((st, dl))) // chips
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= grid, m
+    assert m.temp_size_in_bytes < grid // 100, m
+    text = compiled.as_text()
+    assert not COLLECTIVE.search(text), COLLECTIVE.findall(text)[:5]
+
+
 def test_pallas_backend_refuses_untileable_spec():
     """The paper's element-granular N:M spec (block = out_tile = 1) cannot
     tile onto the compiled kernels: refused at the seam, not in Mosaic."""
