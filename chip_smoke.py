@@ -42,6 +42,10 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
+# Without this the TPU runtime first asks a cloud metadata server for the
+# host's topology; a host with its chips attached and no such server then
+# waits on the query, for seconds or for good, before the chip comes up.
+os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
 
 # serving grid: ~1024 concurrent gesture streams of a few T=50 windows
 N_STREAMS, N_WINDOWS, CHUNK_LEN = 1024, 3, 25

@@ -682,23 +682,25 @@ def scan_chunk(wrep, readout, deltas, layers: LayerState, x_tr,
 
 
 def ordered_slot_sum(x: jax.Array) -> jax.Array:
-    """Reduce the leading slot axis with a shape-fixed binary halving tree.
+    """Reduce the leading slot axis with a shape-fixed adjacent-pair tree.
 
-    ``x``: any ``[S, ...]`` array; returns ``x.sum(0)`` computed as
-    ``(x[:S//2] + x[S//2:2*(S//2)])`` recursively (odd tails ride along one
-    level). Every level is a plain elementwise add of two halves, so the
-    floating-point association order is a function of ``S`` alone — NOT of
-    the device count, sharding, or XLA's reduction strategy. This is what
-    lets the serving layer move the DSST-factor slot reduction onto the
-    device (one tiny ``[L, ·]`` transfer instead of ``[S, L, ·]`` per grid
-    step) while keeping the 1-device and slot-sharded fleets' topology
-    epoch decisions bit-identical — a bare ``x.sum(0)`` would not.
+    ``x``: any ``[S, ...]`` array; returns ``x.sum(0)`` computed level by
+    level as ``x[0::2] + x[1::2]`` (an odd last row rides along to the next
+    level). Every level is a plain elementwise add, so the floating-point
+    association order is a function of ``S`` alone — NOT of the device
+    count, sharding, or XLA's reduction strategy — and the tree sums
+    contiguous blocks first: when a slot mesh gives each of ``D`` devices
+    ``S/D`` contiguous slots and ``S/D`` is a power of two, each device's
+    shard is an exact subtree. The serving chunk fn therefore reduces each
+    shard on its own device and combines the ``[D, ...]`` partials with the
+    tree's top levels (``ordered_slot_sum`` again), bit-identical to the
+    1-device fleet, and no ``[S/D, ...]`` array crosses chips.
     """
     while x.shape[0] > 1:
-        half = x.shape[0] // 2
-        paired = x[:half] + x[half:2 * half]
-        x = paired if x.shape[0] % 2 == 0 else \
-            jnp.concatenate([paired, x[2 * half:]], axis=0)
+        even = x.shape[0] // 2 * 2
+        paired = x[0:even:2] + x[1:even:2]
+        x = paired if even == x.shape[0] else \
+            jnp.concatenate([paired, x[even:]], axis=0)
     return x[0]
 
 

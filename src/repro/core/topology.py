@@ -125,23 +125,26 @@ def install(topo: Topology, params: Dict[str, Any]) -> Dict[str, Any]:
             "hidden": {**params["hidden"], "mask": topo.unit_mask}}
 
 
-def check(mask_or_topo: Union[Topology, jax.Array], cfg) -> bool:
-    """Host-side invariant check: every layer keeps exactly n units per
-    (group, out-tile) and padded rows stay all-False."""
-    mask = mask_or_topo.unit_mask if isinstance(mask_or_topo, Topology) \
-        else mask_or_topo
-    mask = np.asarray(mask)
+def invariant_holds(mask: jax.Array, cfg) -> jax.Array:
+    """The N:M invariant of a stacked mask as a bool scalar (traceable):
+    every layer keeps exactly n units per (group, out-tile) and padded
+    rows stay all-False."""
     if uniform_geometry(cfg):        # no padding: one stacked check
-        return bool(check_unit_mask(jnp.asarray(mask),
-                                    cfg.spec(cfg.layer_fanins[0])))
+        return check_unit_mask(mask, cfg.spec(cfg.layer_fanins[0]))
+    ok = jnp.asarray(True)
     for l, fan_in in enumerate(cfg.layer_fanins):
         spec = cfg.spec(fan_in)
         kb, j = spec.unit_counts(fan_in, cfg.n_hidden)
-        if not bool(check_unit_mask(jnp.asarray(mask[l, :kb, :j]), spec)):
-            return False
-        if mask[l, kb:].any():
-            return False
-    return True
+        ok = ok & check_unit_mask(mask[l, :kb, :j], spec) \
+            & ~mask[l, kb:].any()
+    return ok
+
+
+def check(mask_or_topo: Union[Topology, jax.Array], cfg) -> bool:
+    """Host-side form of :func:`invariant_holds`."""
+    mask = mask_or_topo.unit_mask if isinstance(mask_or_topo, Topology) \
+        else mask_or_topo
+    return bool(invariant_holds(jnp.asarray(np.asarray(mask)), cfg))
 
 
 def dense_masks(mask_stacked: jax.Array, cfg, dtype=jnp.float32) -> jax.Array:
@@ -276,7 +279,8 @@ def weight_unit_scores(w_stacked: jax.Array, cfg) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def topology_epoch(params: Dict[str, Any], pre: jax.Array, post: jax.Array,
-                   cfg, step: Union[int, jax.Array]
+                   cfg, step: Union[int, jax.Array] = 0,
+                   k: Optional[Tuple[int, ...]] = None
                    ) -> Tuple[Dict[str, Any], TopologyStats]:
     """One stacked DSST prune/regrow epoch over every hidden layer.
 
@@ -285,7 +289,9 @@ def topology_epoch(params: Dict[str, Any], pre: jax.Array, post: jax.Array,
     ``DSSTAccumulator`` contents, stacked.  ``step`` selects the recycled
     count ``k`` from ``cfg.dsst``'s decay schedule: a host int resolves it
     statically, a traced array dispatches over the precomputed schedule
-    levels (trace-safe — see ``DSSTConfig.k_levels``).
+    levels (trace-safe — see ``DSSTConfig.k_levels``). A static per-layer
+    ``k`` given by the caller (the serving epoch program, compiled once per
+    schedule level) takes the place of the schedule.
 
     Returns ``(new_params, stats)``; ``new_params`` has the evolved mask
     installed and weights remapped (survivors bit-exact, recycled zeroed),
@@ -298,19 +304,22 @@ def topology_epoch(params: Dict[str, Any], pre: jax.Array, post: jax.Array,
     w = params["hidden"]["w"]
     wscore = weight_unit_scores(w, cfg)
 
+    def at_k(l, spec, fn):
+        return fn(k[l]) if k is not None else scheduled_k_apply(
+            step, cfg.dsst, spec, fn)
+
     if uniform_geometry(cfg):
         spec = cfg.spec(cfg.layer_fanins[0])
-        new_mask, stats = scheduled_k_apply(
-            step, cfg.dsst, spec,
-            lambda k: prune_regrow_factored_stacked(
+        new_mask, stats = at_k(
+            0, spec, lambda k: prune_regrow_factored_stacked(
                 mask, wscore, pre, post, spec, k))
     else:
         new_masks, per_layer = [], []
         for l, fan_in in enumerate(cfg.layer_fanins):
             spec = cfg.spec(fan_in)
             kb, j = spec.unit_counts(fan_in, cfg.n_hidden)
-            nm, st = scheduled_k_apply(
-                step, cfg.dsst, spec,
+            nm, st = at_k(
+                l, spec,
                 lambda k, l=l, spec=spec, kb=kb, j=j: prune_regrow_factored(
                     mask[l, :kb, :j], wscore[l, :kb, :j],
                     pre[l, :kb], post[l, :j], spec, k))

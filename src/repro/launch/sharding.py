@@ -292,9 +292,10 @@ def chunk_step_specs(want_factors: bool = True) -> Tuple[Tuple, Tuple]:
 
     ``want_factors`` mirrors the static flag on ``make_chunk_fn``: when
     False the metrics carry no DSST factor leaves (``pre_mag``/``post_mag``
-    are None) and the spec tree matches; when True the factors leave the
-    shard-mapped step per-slot (``[S, L, ·]`` — the slot reduction happens
-    *outside* shard_map, see ``chunk_step_shardings``).
+    are None) and the spec tree matches; when True each device's factors
+    leave the shard-mapped step already reduced over its slot shard, one
+    ``[1, L, ·]`` partial per device (``[D, L, ·]`` in all), which the
+    jitted chunk fn combines — see ``chunk_step_shardings``.
     """
     from repro.core.snn import ChunkMetrics
     s0, s1 = slot_spec(0), slot_spec(1)
@@ -313,11 +314,11 @@ def chunk_step_shardings(mesh: Mesh,
     """The chunk-fn jit's in/out NamedShardings.
 
     Mostly ``chunk_step_specs`` as shardings, with one deliberate
-    difference: the jitted chunk fn slot-reduces the DSST factors with the
-    order-fixed ``engine.ordered_slot_sum`` *after* the shard-mapped step,
-    so by the time they are jit outputs they have no slot axis — they
-    replicate (``P()``), ``[L, Kmax]`` / ``[L, N]`` and a few KB per grid
-    step instead of an ``[S, L, ·]`` device→host transfer.
+    difference: the jitted chunk fn combines the devices' ``[D, L, ·]``
+    DSST-factor partials with the order-fixed ``engine.ordered_slot_sum``
+    *after* the shard-mapped step, so by the time they are jit outputs
+    they have no slot axis — they replicate (``P()``), ``[L, Kmax]`` /
+    ``[L, N]`` and a few KB per grid step.
     """
     in_specs, out_specs = chunk_step_specs(want_factors)
     as_sh = lambda tree: jax.tree_util.tree_map(

@@ -80,9 +80,12 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
       ``post_mag`` over the chunk and this wrapper slot-reduces them **on
       device** with the order-fixed ``engine.ordered_slot_sum`` before they
       leave the jit: the metrics carry ``[L, Kmax]`` / ``[L, N]`` (a few
-      KB) instead of a per-step ``[S, L, ·]`` device→host transfer, and the
-      fixed reduction tree keeps 1-device and slot-sharded fleets'
-      epoch decisions bit-identical.
+      KB) instead of a per-step ``[S, L, ·]`` device→host transfer. Under
+      a mesh each device reduces its own slot shard inside the
+      ``shard_map`` (one ``[1, L, ·]`` partial each) and the ``[D, L, ·]``
+      partials are combined by the same tree's top levels, so only the
+      partials cross chips and, with a power-of-two shard, the sums are
+      the 1-device fleet's bits.
     * ``False`` — the accumulators are compiled out of the chunk scan
       entirely (``metrics.pre_mag is None``); the O(S·(K+N))-per-timestep
       in-scan cost disappears. Use for fleets with a frozen topology.
@@ -113,6 +116,11 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
             sop_wu_offered=metrics.sop_wu_offered * adapt_mask,
             gate_opened=metrics.gate_opened * adapt_mask[:, None],
             gate_offered=metrics.gate_offered * adapt_mask[:, None])
+        if want_factors and mesh is not None:
+            # this device's slot shard, reduced here: a [1, L, ·] partial
+            metrics = metrics._replace(
+                pre_mag=engine.ordered_slot_sum(metrics.pre_mag)[None],
+                post_mag=engine.ordered_slot_sum(metrics.post_mag)[None])
         return out, new_state, metrics
 
     if mesh is None:
@@ -134,8 +142,8 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
         deltas, state, metrics = body(params, deltas, state, events, valid,
                                       adapt_mask)
         if want_factors:
-            # order-fixed slot reduction OUTSIDE the shard-mapped step (the
-            # step itself stays collective-free) but still on device: the
+            # order-fixed reduction of the [S, L, ·] factors (one device) or
+            # of the [D, L, ·] shard partials (a mesh) on device: the
             # topology service fetches O(L·(K+N)), not O(S·L·(K+N))
             metrics = metrics._replace(
                 pre_mag=engine.ordered_slot_sum(metrics.pre_mag),

@@ -257,6 +257,14 @@ class StreamScheduler:
             # K-reduction order, costing bit-identity with 1-device
             widths = sharding.tier_slot_allocation(
                 [t.n_slots for t in tier_cfgs], mesh)
+            if want_factors:
+                # a power-of-two shard is an exact subtree of the factor
+                # sum's fixed tree (engine.ordered_slot_sum), so each
+                # device reduces its own shard and the fleet's sums stay
+                # the 1-device bits
+                d = sharding.slot_devices(mesh)
+                widths = [d * (1 << (w // d - 1).bit_length())
+                          for w in widths]
             tier_cfgs = [dataclasses.replace(t, n_slots=w)
                          for t, w in zip(tier_cfgs, widths)]
 
@@ -268,12 +276,20 @@ class StreamScheduler:
             tier = _Tier(tc.name, tc.chunk_len, tc.n_slots, slot0)
             slot0 += tc.n_slots
             tier.grid = SlotGrid(tc.n_slots)
-            tier.state = init_stream_state(cfg, tc.n_slots)
-            tier.deltas = init_stream_deltas(cfg, tc.n_slots, compact=compact)
-            if mesh is not None:
-                tier.state_sh = sharding.stream_shardings(tier.state, mesh)
-                tier.state = jax.device_put(tier.state, tier.state_sh)
-                tier.deltas = jax.device_put(tier.deltas, self._delta_sh)
+            make_state = lambda n=tc.n_slots: init_stream_state(cfg, n)
+            make_deltas = lambda n=tc.n_slots: init_stream_deltas(
+                cfg, n, compact=compact)
+            if mesh is None:
+                tier.state, tier.deltas = make_state(), make_deltas()
+            else:
+                # made in place on each device's shard: never the whole
+                # grid on one device
+                tier.state_sh = sharding.stream_shardings(
+                    jax.eval_shape(make_state), mesh)
+                tier.state = jax.jit(make_state,
+                                     out_shardings=tier.state_sh)()
+                tier.deltas = jax.jit(make_deltas,
+                                      out_shardings=self._delta_sh)()
             # one compiled chunk fn and lane reset per tier (their own [S]
             # static shapes and trace counters); all tiers share cfg/adapt/
             # exec rep
@@ -330,19 +346,23 @@ class StreamScheduler:
             ap.note_depth(0, pipeline_depth)
             self.autopilot = ap
 
+        if mesh is not None:
+            # the dense base replicated on the mesh, as every epoch
+            # program returns it (one epoch compile, not two)
+            self.params = jax.device_put(params, sharding.replicated(mesh))
         self._refresh_exec_params()
 
     def _refresh_exec_params(self) -> None:
-        """(Re)derive what the chunk fns actually consume from the canonical
-        dense ``self.params`` — the mask-free compact rep in compact mode —
-        and re-measure the resident serving bytes. Host-side; runs at
-        construction and after every topology swap (the only times the base
-        weights change).
+        """Derive what the chunk fns consume from the canonical dense
+        ``self.params`` — the mask-free compact rep in compact mode — and
+        measure the resident serving bytes. Runs at construction; a
+        topology epoch's program returns the next exec rep itself, with
+        the same shapes, so the bytes stay as measured.
 
-        Under a mesh the exec params are placed replicated on it every
-        time: an epoch leaves them committed to the mesh while the
-        constructor's are not, and the chunk fn's jit would trace that
-        difference as a new input type (a recompile per first swap)."""
+        Under a mesh the exec params are placed replicated on it, as the
+        epoch program returns them: the chunk fn's jit would trace a
+        difference in placement as a new input type (a recompile at the
+        first swap)."""
         self._exec_params = (serving_params(self.params, self.cfg)
                              if self.compact else self.params)
         if self.mesh is not None:
@@ -386,14 +406,6 @@ class StreamScheduler:
         semantics."""
         if self.ingest is not None:
             self.ingest.stop()
-
-    def _replace_lanes(self, tier: _Tier, state, deltas) -> None:
-        """Install state/deltas made outside the scheduler (a topology
-        epoch's) on ``tier``, restoring the slot sharding under a mesh."""
-        if self.mesh is not None:
-            state = jax.device_put(state, tier.state_sh)
-            deltas = jax.device_put(deltas, self._delta_sh)
-        tier.state, tier.deltas = state, deltas
 
     def _admit(self, tier: _Tier) -> None:
         """Claim free lanes for queued sessions and reset them all in
@@ -581,7 +593,10 @@ class StreamScheduler:
             with self.tracer.span("sched.device_wait",
                                   grid_step=fl.grid_step):
                 tw0 = time.perf_counter()
-                m = jax.device_get(fl.metrics)  # one transfer for all metrics
+                # one transfer for all metrics; the DSST factors are
+                # fetched by retire.factors
+                m = jax.device_get(fl.metrics._replace(pre_mag=None,
+                                                       post_mag=None))
                 wait_s = time.perf_counter() - tw0
             # fl.queued_s: host work done while this step was in flight
             # (stamped by StagingPipeline.push/pop; 0.0 on the serial path)
@@ -631,8 +646,14 @@ class StreamScheduler:
             sp.set(d2h_bytes=nbytes([sess.final_deltas
                                      for _, sess in staged.retiring]))
         svc = self.topology
-        if svc is not None and not svc.frozen and m.pre_mag is not None:
-            svc.observe(m)
+        if svc is not None and not svc.frozen and \
+                fl.metrics.pre_mag is not None:
+            with self.tracer.span("retire.factors",
+                                  grid_step=fl.grid_step) as sp:
+                pre, post = jax.device_get((fl.metrics.pre_mag,
+                                            fl.metrics.post_mag))
+                sp.set(d2h_bytes=nbytes((pre, post)))
+                svc.observe(m._replace(pre_mag=pre, post_mag=post))
             self.maybe_evolve_topology(merge_slots=staged.merge_slots,
                                        grid_step=fl.grid_step)
 
@@ -756,10 +777,15 @@ class StreamScheduler:
                               grid_step: Optional[int] = None):
         """Run a due DSST prune/regrow epoch between grid steps.
 
-        The service returns ``(params, deltas)`` with identical shapes and
-        slot shardings, so installing them is an atomic swap: active
+        The epoch is one program call (``epoch.enqueue``; the delta grid is
+        donated to it) whose new base, exec rep and deltas keep their
+        shapes and slot shardings, so installing them (``epoch.install``,
+        which also fetches the epoch's stats) is an atomic swap: active
         sessions keep their lanes and carried state, and the next grid step
-        reuses the already-compiled chunk fn (``n_compiles`` stays 1).
+        reuses the already-compiled chunk fn (``n_compiles`` stays 1). The
+        ``topology.epoch`` span carries ``pruned``, ``regrown``, ``merged``,
+        ``mask_change`` and ``bytes_projected`` (one device's delta shard,
+        read and written).
         The retire phase passes the staged step's ``merge_slots`` snapshot
         and dispatch-time ``grid_step`` so a pipelined epoch sees exactly
         the fleet the serial scheduler would; manual calls may omit both
@@ -777,14 +803,21 @@ class StreamScheduler:
                 if sess is not None and sess.adapt)
         with self.tracer.span("topology.epoch", grid_step=step,
                               epoch=svc.epoch_idx) as sp:
-            params, deltas, event = svc.evolve(
-                self.params, tier.deltas, merge_slots=merge_slots,
-                grid_step=step)
+            for fl in tier.pipeline:      # the grid is donated below
+                if fl.deltas is tier.deltas:
+                    fl.take_snapshots()
+            with self.tracer.span("epoch.enqueue", grid_step=step):
+                run = svc.enqueue(self.params, tier.deltas,
+                                  merge_slots=merge_slots, grid_step=step,
+                                  mesh=self.mesh)
+            with self.tracer.span("epoch.install", grid_step=step):
+                self.params, self._exec_params = run.params, run.exec_params
+                tier.deltas = run.deltas
+                event = svc.resolve(run)
             sp.set(pruned=event.pruned, regrown=event.regrown,
-                   merged=len(event.merged_slots))
-        self.params = params
-        self._replace_lanes(tier, tier.state, deltas)
-        self._refresh_exec_params()   # new mask → new compact wc/idx
+                   merged=len(event.merged_slots),
+                   mask_change=event.mask_change,
+                   bytes_projected=run.bytes_projected)
         self.telemetry.record_topology_epoch(
             grid_step=event.grid_step, pruned=event.pruned,
             regrown=event.regrown, mask_change=event.mask_change,

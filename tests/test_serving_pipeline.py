@@ -275,23 +275,31 @@ def test_live_topology_requires_factors(params):
 
 def test_ordered_slot_sum_fixed_tree():
     """The reduction tree is a function of S alone: equals an explicit
-    pairwise-halving reference bit-for-bit, for odd and even S, and is
-    invariant to how the array is later split (the sharded-parity
-    mechanism, testable without devices)."""
+    adjacent-pair reference bit-for-bit, for odd and even S, and is
+    invariant to how the array is split — each power-of-two contiguous
+    block reduced on its own, then the block sums by the same tree, is the
+    same bits (the sharded-parity mechanism, testable without devices)."""
     rng = np.random.default_rng(0)
+
+    def ref(a):
+        while a.shape[0] > 1:
+            e = a.shape[0] // 2 * 2
+            p = a[0:e:2] + a[1:e:2]
+            a = p if e == a.shape[0] else np.concatenate([p, a[e:]], 0)
+        return a[0]
+
     for S in (1, 2, 3, 7, 8, 16):
         x = (rng.standard_normal((S, 4, 5)).astype(np.float32) * 1e3)
-
-        def ref(a):
-            while a.shape[0] > 1:
-                h = a.shape[0] // 2
-                p = a[:h] + a[h:2 * h]
-                a = p if a.shape[0] % 2 == 0 else \
-                    np.concatenate([p, a[2 * h:]], 0)
-            return a[0]
-
         got = np.asarray(engine.ordered_slot_sum(jnp.asarray(x)))
         np.testing.assert_array_equal(got, ref(x))
         # and under jit (the form the chunk fn actually runs)
         jitted = np.asarray(jax.jit(engine.ordered_slot_sum)(jnp.asarray(x)))
         np.testing.assert_array_equal(jitted, ref(x))
+    # split into D contiguous shards of S/D (a power of two): the shard
+    # subtrees, combined by the tree's top levels, are the whole tree
+    for S, D in ((16, 4), (16, 8), (32, 2), (64, 4)):
+        x = (rng.standard_normal((S, 4, 5)).astype(np.float32) * 1e3)
+        parts = jnp.stack([engine.ordered_slot_sum(jnp.asarray(b))
+                           for b in np.split(x, D)])
+        np.testing.assert_array_equal(
+            np.asarray(engine.ordered_slot_sum(parts)), ref(x))

@@ -23,9 +23,9 @@ import pytest
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _run(code: str) -> str:
+def _run(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -229,3 +229,89 @@ def test_sharded_topology_evolution_parity():
                 np.testing.assert_array_equal(a.logits, b.logits)
         print("OK")
     """))
+
+
+def test_live_fleet_4device_bit_identical_factors_and_epochs():
+    """Live DSST on a 4-device slot mesh against one device: every grid
+    step's cross-device factor sums (each device's shard reduced in the
+    step, the four partials combined by the adjacent-pair tree), every
+    jitted epoch's delta norms and hot lanes, and the evolved base, delta
+    grid and window logits are the same bits; the chunk step and the
+    epoch program each compile once."""
+    print(_run("""
+        import numpy as np, jax
+        from repro.core.dsst import DSSTConfig
+        from repro.core.snn import SNNConfig, init_params
+        from repro.launch.mesh import make_serving_mesh
+        from repro.serving import (ReplaySource, StreamScheduler,
+                                   StreamSession, TopologyService,
+                                   TopologyServiceConfig)
+
+        cfg = SNNConfig(n_in=32, n_hidden=32, n_layers=2, n_out=8,
+                        t_steps=12, dsst=DSSTConfig(period=4, prune_frac=0.5))
+        params = init_params(jax.random.PRNGKey(3), cfg)
+
+        class Recording(TopologyService):
+            def __init__(self, *a):
+                super().__init__(*a)
+                self.factors, self.records = [], []
+            def observe(self, m):
+                self.factors.append((np.asarray(m.pre_mag),
+                                     np.asarray(m.post_mag)))
+                super().observe(m)
+            def enqueue(self, *a, **k):
+                run = super().enqueue(*a, **k)
+                self.records.append(run.record)
+                return run
+
+        def drive(mesh):
+            svc = Recording(cfg, TopologyServiceConfig(
+                epoch_every=2, merge_top=2))
+            sched = StreamScheduler(params, cfg, n_slots=8, chunk_len=6,
+                                    mesh=mesh, topology=svc,
+                                    pipeline_depth=1)
+            for sid in range(8):
+                r = np.random.default_rng(sid)
+                ev = (r.random((48, cfg.n_in)) < 0.3).astype(np.float32)
+                sched.submit(StreamSession(
+                    sid=sid, source=ReplaySource(ev, chunk_len=6)))
+            done = {s.sid: s for s in sched.run_until_drained()}
+            return sched, svc, done
+
+        s1, v1, d1 = drive(None)
+        s4, v4, d4 = drive(make_serving_mesh(4))
+        assert s4.n_slots == 8 and v1.epoch_idx >= 2
+        # 3 slots a device round up to 4: a shard is an exact subtree
+        assert StreamScheduler(params, cfg, n_slots=12,
+                               mesh=make_serving_mesh(4),
+                               topology=Recording(cfg)).n_slots == 16
+        assert v4.epoch_idx == v1.epoch_idx
+        assert s1.n_compiles == 1 and s4.n_compiles == 1, \\
+            (s1.n_compiles, s4.n_compiles)
+        assert v1.n_program_traces == 1 and v4.n_program_traces == 1, \\
+            (v1.n_program_traces, v4.n_program_traces)
+        assert len(v1.factors) == len(v4.factors) > 0
+        for (a, b), (c, d) in zip(v1.factors, v4.factors):
+            np.testing.assert_array_equal(a, c)
+            np.testing.assert_array_equal(b, d)
+        for r1, r4 in zip(v1.records, v4.records):
+            np.testing.assert_array_equal(np.asarray(r1.norms),
+                                          np.asarray(r4.norms))
+            np.testing.assert_array_equal(np.asarray(r1.hot),
+                                          np.asarray(r4.hot))
+            np.testing.assert_array_equal(np.asarray(r1.hot_deltas),
+                                          np.asarray(r4.hot_deltas))
+        assert [e.merged_slots for e in v1.events] == \
+            [e.merged_slots for e in v4.events]
+        assert any(e.merged_slots for e in v1.events)
+        for a, b in zip(jax.tree_util.tree_leaves(s1.params),
+                        jax.tree_util.tree_leaves(s4.params)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(s1.deltas),
+                                      np.asarray(s4.deltas))
+        for sid in d1:
+            assert len(d1[sid].predictions) == len(d4[sid].predictions) > 0
+            for a, b in zip(d1[sid].predictions, d4[sid].predictions):
+                np.testing.assert_array_equal(a.logits, b.logits)
+        print("OK")
+    """, devices=4))

@@ -145,6 +145,78 @@ def test_sharded_chunk_fn_has_no_collectives(topo):
     assert not COLLECTIVE.search(text), COLLECTIVE.findall(text)[:5]
 
 
+def _collective_dims(text: str):
+    """Every dimension of every shape on the HLO lines that hold a
+    collective (its result and its operands)."""
+    dims = set()
+    for line in text.splitlines():
+        if COLLECTIVE.search(line):
+            for shape in re.findall(r"\b[a-z]+\d*\[([\d,]*)\]", line):
+                dims.update(int(d) for d in shape.split(",") if d)
+    return dims
+
+
+LIVE_SLOTS = 4096                   # the live four-chip cell's fleet
+
+
+def test_live_chunk_fn_moves_no_slot_shard(topo):
+    """With live DSST on the 4-chip slot mesh, each chip reduces its own
+    slots' factors inside the step: the only collective left combines the
+    four [1, L, ·] partials, and none carries the slot extent (S or
+    S/4)."""
+    from repro.launch import sharding
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("slots",))
+    fn = make_chunk_fn(CONFIG, mesh=mesh, want_factors=True)
+    rep, slot = sharding.replicated(mesh), sharding.slot_sharding(mesh)
+    p = jax.eval_shape(lambda: snn.serving_params(
+        snn.init_params(jax.random.PRNGKey(0), CONFIG), CONFIG))
+    dl = jax.eval_shape(lambda: snn.init_stream_deltas(CONFIG, LIVE_SLOTS))
+    st = jax.eval_shape(lambda: snn.init_stream_state(CONFIG, LIVE_SLOTS))
+    col = sharding.slot_sharding(mesh, 1)
+    compiled = fn.lower(
+        _shapes(p, rep), _shapes(dl, slot), _shapes(st, slot),
+        jax.ShapeDtypeStruct((CHUNK_LEN, LIVE_SLOTS, CONFIG.n_in),
+                             jnp.float32, sharding=col),
+        jax.ShapeDtypeStruct((CHUNK_LEN, LIVE_SLOTS), jnp.bool_,
+                             sharding=col),
+        jax.ShapeDtypeStruct((LIVE_SLOTS,), jnp.bool_,
+                             sharding=slot)).compile()
+    dims = _collective_dims(compiled.as_text())
+    assert not dims & {LIVE_SLOTS, LIVE_SLOTS // 4}, sorted(dims)
+    assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "mesh4"])
+def test_epoch_program_is_in_place(topo, chips):
+    """The live epoch program at the paper's widths: the donated delta
+    grid is its output (aliased) and its temporaries stay far under one
+    chip's shard of the grid — no second grid, on one chip or four."""
+    from repro.launch import sharding
+    from repro.serving.topology_service import (TopologyServiceConfig,
+                                                make_epoch_program)
+    S = N_SLOTS * chips
+    mesh = (Mesh(np.asarray(topo.devices[:chips]), ("slots",))
+            if chips > 1 else None)
+    rep = sharding.replicated(mesh) if mesh else SingleDeviceSharding(
+        topo.devices[0])
+    slot = sharding.slot_sharding(mesh) if mesh else rep
+    fn = make_epoch_program(CONFIG, TopologyServiceConfig(merge_top=2),
+                            mesh=mesh)
+    p = jax.eval_shape(lambda: snn.init_params(jax.random.PRNGKey(0),
+                                               CONFIG))
+    dl = jax.eval_shape(lambda: snn.init_stream_deltas(CONFIG, S))
+    fac = jax.ShapeDtypeStruct((CONFIG.n_layers, CONFIG.n_hidden),
+                               jnp.float32, sharding=rep)
+    compiled = fn.lower(
+        _shapes(p, rep), _shapes(dl, slot), fac, fac,
+        jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=slot),
+        (6, 6)).compile()
+    shard = dl.size * dl.dtype.itemsize // chips
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= shard, m
+    assert m.temp_size_in_bytes < shard // 50, m
+
+
 @pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "mesh4"])
 def test_lane_reset_is_in_place(topo, chips):
     """Admission's lane reset at the smoke's grid: the donated grids are
