@@ -71,7 +71,7 @@ def _snn_cfg():
 
 
 def _snn_inputs(cfg, *, compact: bool, chunk_len: int = _C,
-                n_slots: int = _S):
+                n_slots: int = _S, packed: bool = False):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -81,21 +81,25 @@ def _snn_inputs(cfg, *, compact: bool, chunk_len: int = _C,
     deltas = snn.init_stream_deltas(cfg, n_slots, compact=compact)
     state = snn.init_stream_state(cfg, n_slots)
     rng = np.random.default_rng(0)
-    events = jnp.asarray(
-        rng.random((chunk_len, n_slots, cfg.n_in)) < 0.25, jnp.float32)
+    spikes = rng.random((chunk_len, n_slots, cfg.n_in)) < 0.25
+    # packed: the uint8 bit rows the scheduler stages
+    events = jnp.asarray(np.packbits(spikes, axis=-1) if packed
+                         else spikes.astype(np.float32))
     valid = jnp.ones((chunk_len, n_slots), bool)
     amask = jnp.ones((n_slots,), bool)
     return params, deltas, state, events, valid, amask
 
 
 def _chunk_entry(*, mesh=None, want_factors: bool, compact: bool,
-                 chunk_len: int = _C, n_slots: int = _S):
+                 chunk_len: int = _C, n_slots: int = _S,
+                 packed: bool = False):
     from repro.core import snn
     from repro.serving.adapt import AdaptConfig, make_chunk_fn
 
     cfg = _snn_cfg()
     params, deltas, state, events, valid, amask = _snn_inputs(
-        cfg, compact=compact, chunk_len=chunk_len, n_slots=n_slots)
+        cfg, compact=compact, chunk_len=chunk_len, n_slots=n_slots,
+        packed=packed)
     exec_params = snn.serving_params(params, cfg) if compact else params
     fn = make_chunk_fn(cfg, AdaptConfig(), mesh=mesh,
                        want_factors=want_factors)
@@ -125,6 +129,19 @@ def _chunk_compact_factors():
     """The default serving hot path: mask-free exec params, compact deltas,
     DSST factors slot-reduced on device."""
     return _chunk_entry(want_factors=True, compact=True)
+
+
+@register("serving.chunk_fn[compact,factors,packed]")
+def _chunk_compact_factors_packed():
+    """The program the scheduler runs: the default hot path fed packed
+    uint8 spike rows, widened to float32 inside the step."""
+    return _chunk_entry(want_factors=True, compact=True, packed=True)
+
+
+@register("serving.chunk_fn[compact,frozen,packed]")
+def _chunk_compact_frozen_packed():
+    """The frozen-topology fleet's program as the scheduler runs it."""
+    return _chunk_entry(want_factors=False, compact=True, packed=True)
 
 
 @register("serving.chunk_fn[compact,frozen]")
@@ -167,6 +184,15 @@ def _chunk_sharded():
     from repro.launch.mesh import make_serving_mesh
     return _chunk_entry(mesh=make_serving_mesh(), want_factors=True,
                         compact=True)
+
+
+@register("serving.chunk_fn[sharded,packed]")
+def _chunk_sharded_packed():
+    """The slot-axis shard_map path fed packed spike rows: each device
+    widens only its own slot shard, still with no collective."""
+    from repro.launch.mesh import make_serving_mesh
+    return _chunk_entry(mesh=make_serving_mesh(), want_factors=True,
+                        compact=True, packed=True)
 
 
 @register("snn.run_chunk[compact]")

@@ -14,7 +14,7 @@ from .checkpointing import restore_fleet, save_fleet
 from .ingest import IngestConfig, IngestWorker
 from .scheduler import StreamScheduler, TierConfig
 from .session import (SessionStatus, StreamSession, WindowPrediction,
-                      fresh_lane_state, read_lane, write_lane)
+                      fresh_lane_state, read_lane, unpack_events, write_lane)
 from .staging import InFlight, LaneRecord, StagedChunk, StagingPipeline
 from .stream_source import (AERStreamSource, ArrivalConfig, ReplaySource,
                             TaskStreamSource, aer_decode, aer_encode)
@@ -31,5 +31,5 @@ __all__ = [
     "TopologyService", "TopologyServiceConfig", "WindowPrediction",
     "aer_decode", "aer_encode", "delta_norms", "fresh_lane_state",
     "make_chunk_fn", "merge_lane_into_base", "read_lane",
-    "restore_fleet", "save_fleet", "write_lane",
+    "restore_fleet", "save_fleet", "unpack_events", "write_lane",
 ]

@@ -61,9 +61,14 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
     """Build the jitted slot-grid step.
 
     Returns ``fn(params, deltas, state, events, valid, adapt_mask)`` ->
-    ``(deltas, state, metrics)`` with static shapes: ``events`` [C, S, n_in],
-    ``valid`` [C, S] bool, ``adapt_mask`` [S] bool. One compilation serves
-    any number of streams multiplexed through the S slots.
+    ``(deltas, state, metrics)`` with static shapes: ``events`` [C, S, n_in]
+    float spikes or [C, S, ceil(n_in/8)] uint8 packed bits
+    (``np.packbits`` along the last axis, what the scheduler stages),
+    ``valid`` [C, S] bool, ``adapt_mask`` [S] bool. Packed events are
+    widened to float32 on the device ahead of the chunk scan, which then
+    runs exactly the float32 program: spikes are 0 or 1, so both forms
+    compute the same bits. One compilation per events dtype serves any
+    number of streams multiplexed through the S slots.
 
     With ``mesh`` (a 1-D ``("slots",)`` mesh), the step runs under
     ``shard_map`` with explicit slot-leading in/out shardings: ``deltas``,
@@ -97,6 +102,12 @@ def make_chunk_fn(cfg: SNNConfig, adapt: AdaptConfig | None = None,
 
     def step(params, deltas, state: StreamState, events, valid, adapt_mask
              ) -> Tuple[jax.Array, StreamState, ChunkMetrics]:
+        if events.dtype == jnp.uint8:
+            # packed spike bits, widened ahead of the chunk scan (which
+            # then runs the float32 program unchanged); under a mesh each
+            # device widens only its own slot shard
+            events = jnp.unpackbits(events, axis=-1,
+                                    count=cfg.n_in).astype(jnp.float32)
         new_deltas, new_state, metrics = run_chunk(
             params, deltas, state, events, valid, scfg, learn=adapt.enabled,
             want_factors=want_factors)

@@ -3,11 +3,11 @@
 ``StreamScheduler`` generalizes the continuous batcher's fixed slot grid
 (``launch.batching.SlotGrid``) from token decode to SNN timesteps. One
 jitted chunk step with static shapes — events ``[chunk_len, n_slots,
-n_in]``, valid ``[chunk_len, n_slots]`` — advances every active stream by
-up to ``chunk_len`` timesteps; admitted streams claim a lane (reset in
-place), retired streams free it. Idle or ragged tails are masked invalid,
-so they neither perturb state nor accrue telemetry: an empty slot costs
-exactly zero counted events.
+ceil(n_in/8)]`` packed spike bits, valid ``[chunk_len, n_slots]`` —
+advances every active stream by up to ``chunk_len`` timesteps; admitted
+streams claim a lane (reset in place), retired streams free it. Idle or
+ragged tails are masked invalid, so they neither perturb state nor accrue
+telemetry: an empty slot costs exactly zero counted events.
 
 Each grid step runs through three explicit phases (see serving/staging.py):
 
@@ -97,7 +97,7 @@ from .adapt import AdaptConfig, make_chunk_fn
 from .autopilot import AutopilotConfig, DepthAutopilot
 from .ingest import IngestConfig, IngestWorker
 from .session import (SessionStatus, StreamSession, WindowPrediction,
-                      make_reset_lanes, nbytes)
+                      make_reset_lanes, nbytes, packed_width)
 from .staging import InFlight, LaneRecord, StagedChunk, StagingPipeline
 from .telemetry import FleetTelemetry
 
@@ -512,12 +512,13 @@ class StreamScheduler:
         self._admit(tier)
 
         C, S = tier.chunk_len, tier.n_slots
-        events = np.zeros((C, S, self.cfg.n_in), np.float32)
-        valid = np.zeros((C, S), bool)
+        # packed-bit rows, as the sessions buffer them; the chunk fn
+        # widens them to float32 on the device
+        events = np.zeros((C, S, packed_width(self.cfg.n_in)), np.uint8)
+        n_fed = np.zeros(S, np.int32)
         amask = np.zeros(S, bool)
-        lanes: List[LaneRecord] = []
+        occupied = []
         retiring = []
-        fed: Dict[int, int] = {}
         for slot, sess in enumerate(tier.grid.occupant):
             if sess is None:
                 continue
@@ -525,13 +526,19 @@ class StreamScheduler:
             n = chunk.shape[0]
             if n:
                 events[:n, slot] = chunk
-                valid[:n, slot] = True
+            n_fed[slot] = n
             amask[slot] = sess.adapt
-            fed[slot] = n
-            lanes.append(LaneRecord(slot=slot, session=sess, n_fed=n,
-                                    events_in=float(chunk.sum())))
+            occupied.append((slot, sess, n))
             if sess.exhausted:        # a host fact: source done, buffers empty
                 retiring.append((slot, sess))
+        valid = np.arange(C)[:, None] < n_fed      # [C, S]: ragged tails off
+        # each lane's exact spike count: the set bits of its column
+        spikes = (np.bitwise_count(events).sum(axis=0, dtype=np.int32)
+                  .sum(axis=1).tolist() if occupied else [])
+        lanes = [LaneRecord(slot=slot, session=sess, n_fed=n,
+                            events_in=float(spikes[slot]))
+                 for slot, sess, n in occupied]
+        fed = {slot: n for slot, _, n in occupied}
         gone = {slot for slot, _ in retiring}
         merge_slots = tuple(
             slot for slot, sess in enumerate(tier.grid.occupant)

@@ -2,7 +2,11 @@
 
 A ``StreamSession`` is the host-side record of one event stream: its
 identity, lifecycle status, buffered-but-unprocessed event chunks, emitted
-window predictions, and accumulated per-stream telemetry. The *device*-side
+window predictions, and accumulated per-stream telemetry. Buffered spikes
+are held as packed bits (:func:`pack_events`: ``np.packbits`` rows,
+big-endian bit order, ``ceil(n_in / 8)`` bytes a timestep), the form the
+scheduler stages and the chunk fn widens on the device;
+:func:`unpack_events` gives the spikes back. The *device*-side
 state (membrane potentials, the three-trace neuron SRAM, per-stream gate
 thresholds, per-stream weight deltas) lives in batched pytrees whose leading
 axis is the slot index — sessions only remember *which lane* is theirs.
@@ -52,13 +56,14 @@ class StreamSession:
     adapt: bool = True                      # OSSL adaptation on for this stream
     n_in: Optional[int] = None              # event width; learned on first
     #   push, or stamped by the scheduler at submit — keeps pop_chunk's
-    #   empty result a well-shaped [0, n_in] (not a [0, 0] broadcast trap)
+    #   empty result a well-shaped [0, ceil(n_in/8)] (not a [0, 0] trap)
     tier: Optional[str] = None              # QoS tier; resolved at submit
     status: SessionStatus = SessionStatus.QUEUED
     slot: Optional[int] = None
     timesteps_fed: int = 0
     predictions: List[WindowPrediction] = dataclasses.field(default_factory=list)
-    # buffered events that arrived but have not been stepped yet
+    # buffered events that arrived but have not been stepped yet, as
+    # packed-bit rows [c, ceil(n_in/8)] uint8
     _pending: List[np.ndarray] = dataclasses.field(default_factory=list)
     # the IngestWorker holding this session's queued-but-undrained chunks
     # (set by IngestWorker.attach, cleared at detach); consulted by
@@ -73,7 +78,10 @@ class StreamSession:
 
     # -- event buffering -----------------------------------------------------
     def push_events(self, chunk: np.ndarray) -> None:
-        """chunk: [c, n_in] binary spikes, any c >= 1."""
+        """chunk: [c, n_in] binary spikes (every value 0 or 1, any dtype),
+        any c >= 1; buffered as packed bits. A value other than 0 or 1
+        raises ``ValueError``."""
+        chunk = np.asarray(chunk)
         if chunk.ndim != 2:
             raise ValueError(f"chunk must be [c, n_in], got {chunk.shape}")
         if self.n_in is None:
@@ -81,14 +89,16 @@ class StreamSession:
         elif chunk.shape[1] != self.n_in:
             raise ValueError(
                 f"chunk width {chunk.shape[1]} != session n_in {self.n_in}")
-        self._pending.append(np.asarray(chunk, np.float32))
+        self._pending.append(pack_events(chunk))
 
     def pending_timesteps(self) -> int:
         """Buffered-but-unprocessed timesteps across all pending chunks."""
         return sum(c.shape[0] for c in self._pending)
 
     def pop_chunk(self, max_len: int) -> np.ndarray:
-        """Pop up to ``max_len`` buffered timesteps as one [c, n_in] array."""
+        """Pop up to ``max_len`` buffered timesteps as one packed-bit
+        ``[c, ceil(n_in/8)]`` uint8 array (:func:`unpack_events` widens
+        it); ``shape[0]`` is the number of timesteps popped."""
         out, need = [], max_len
         while self._pending and need > 0:
             head = self._pending[0]
@@ -100,8 +110,8 @@ class StreamSession:
                 self._pending[0] = head[need:]
                 need = 0
         if not out:
-            return np.zeros((0, self.n_in or 0), np.float32)
-        return np.concatenate(out, axis=0)
+            return np.zeros((0, packed_width(self.n_in or 0)), np.uint8)
+        return out[0] if len(out) == 1 else np.concatenate(out, axis=0)
 
     @property
     def exhausted(self) -> bool:
@@ -119,6 +129,33 @@ class StreamSession:
         src_done = self.source is None or self.source.exhausted
         queued = self._ingest is not None and self._ingest.has_pending(self.sid)
         return src_done and not queued and not self._pending
+
+
+# ---------------------------------------------------------------------------
+# packed spike rows
+# ---------------------------------------------------------------------------
+
+def packed_width(n_in: int) -> int:
+    """Bytes of one packed timestep of ``n_in`` inputs."""
+    return -(-n_in // 8)
+
+
+def pack_events(chunk: np.ndarray) -> np.ndarray:
+    """``[c, n_in]`` binary spikes -> ``[c, ceil(n_in/8)]`` uint8 rows
+    (``np.packbits`` along the last axis, big-endian bit order, zero
+    padding bits). Raises ``ValueError`` unless every value is 0 or 1."""
+    spikes = chunk == 1
+    # a value differs from its spike bit unless it is 0 or 1 (NaN never
+    # equals): an exact binary check in two passes
+    if np.count_nonzero(chunk != spikes):
+        raise ValueError("chunk must hold binary spikes (every value 0 or 1)")
+    return np.packbits(spikes, axis=-1)
+
+
+def unpack_events(rows: np.ndarray, n_in: int) -> np.ndarray:
+    """Packed ``[..., ceil(n_in/8)]`` uint8 rows -> ``[..., n_in]`` float32
+    spikes: the inverse of :func:`pack_events`."""
+    return np.unpackbits(rows, axis=-1, count=n_in).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------

@@ -9,10 +9,10 @@ step is split into three explicit phases —
 
 * **stage**   — host work: advance the virtual clock, poll sources, admit
   queued sessions (enqueueing one lane-reset program, never waiting on
-  the device), pack the ``[C, S, n_in]`` event / ``[C, S]`` valid
-  buffers, and *decide* which sessions will exhaust after this step (a
-  pure host fact: source done + pending buffer drained).  Produces a
-  :class:`StagedChunk`.
+  the device), pack the ``[C, S, ceil(n_in/8)]`` packed-bit event /
+  ``[C, S]`` valid buffers, and *decide* which sessions will exhaust
+  after this step (a pure host fact: source done + pending buffer
+  drained).  Produces a :class:`StagedChunk`.
 * **dispatch** — enqueue the chunk fn on the staged buffers and return
   immediately (JAX dispatch is asynchronous); the device handles plus the
   staged host record become an :class:`InFlight` step.
@@ -59,16 +59,17 @@ class LaneRecord:
 class StagedChunk:
     """One grid step's host-assembled inputs + scheduling decisions.
 
-    ``events [C, S, n_in]`` / ``valid [C, S]`` / ``adapt_mask [S]`` are the
-    chunk fn's staging buffers.  ``retiring`` lists the ``(slot, session)``
-    pairs that exhaust after this step — known at stage time, finalized at
-    retire time.  ``merge_slots`` snapshots the adaptive occupants eligible
-    for a hot-stream fold should a topology epoch run after this step
-    (captured here so a pipelined retire sees the same candidate set the
-    serial scheduler would — admissions from *later* stage phases must not
-    leak into an earlier step's epoch).
+    ``events [C, S, ceil(n_in/8)]`` (packed spike bits) / ``valid [C, S]``
+    / ``adapt_mask [S]`` are the chunk fn's staging buffers.  ``retiring``
+    lists the ``(slot, session)`` pairs that exhaust after this step —
+    known at stage time, finalized at retire time.  ``merge_slots``
+    snapshots the adaptive occupants eligible for a hot-stream fold should
+    a topology epoch run after this step (captured here so a pipelined
+    retire sees the same candidate set the serial scheduler would —
+    admissions from *later* stage phases must not leak into an earlier
+    step's epoch).
     """
-    events: Any                  # np.ndarray [C, S, n_in] f32
+    events: Any                  # np.ndarray [C, S, ceil(n_in/8)] uint8
     valid: Any                   # np.ndarray [C, S] bool
     adapt_mask: Any              # np.ndarray [S] bool
     lanes: List[LaneRecord]

@@ -322,7 +322,10 @@ def test_registry_every_entrypoint_passes():
     assert set(reports) == set(registry.names())
     assert {"serving.chunk_fn[compact,factors]",
             "serving.chunk_fn[compact,frozen]", "serving.chunk_fn[dense]",
-            "serving.chunk_fn[sharded]", "snn.run_chunk[compact]",
+            "serving.chunk_fn[sharded]",
+            "serving.chunk_fn[compact,factors,packed]",
+            "serving.chunk_fn[compact,frozen,packed]",
+            "serving.chunk_fn[sharded,packed]", "snn.run_chunk[compact]",
             "snn.run_chunk[dense]", "launch.decode_step"} <= set(reports)
     for name, r in reports.items():
         assert r.ok, f"{name}:\n{r}"

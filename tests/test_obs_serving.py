@@ -358,7 +358,8 @@ def test_admit_dispatch_and_snapshot_byte_counts(frozen_runs):
         assert s.attr("programs") == int(n > 0), s
         assert s.attr("bytes_written") == n * per_session, s
         assert s.attr("leaves_written") == (n_leaves if n else 0), s
-    h2d = C * S * CFG.n_in * 4 + C * S + S      # f32 events, bool valid/mask
+    # packed-bit events, bool valid/mask
+    h2d = C * S * -(-CFG.n_in // 8) + C * S + S
     dispatches = tr.spans("sched.dispatch")
     assert {s.attr("h2d_bytes") for s in dispatches} == {h2d}
     lane_delta = _nbytes(init_stream_deltas(CFG, 1))
@@ -428,7 +429,7 @@ def test_tracing_8device_bit_identical_with_readmission(params):
         assert len(tr.spans("admit.write")) == len(busy) < 40
         S = s1.n_slots
         assert {s.attr("h2d_bytes") for s in tr.spans("sched.dispatch")} \
-            == {4 * S * cfg.n_in * 4 + 4 * S + S}
+            == {4 * S * (cfg.n_in // 8) + 4 * S + S}    # packed-bit events
         assert s1.deltas.sharding.is_equivalent_to(s0.deltas.sharding, 6)
         print("OK")
     """)

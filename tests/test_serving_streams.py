@@ -306,14 +306,16 @@ def test_zero_recompilation_across_traffic_patterns(params):
 
 def test_pop_chunk_empty_is_column_shaped():
     """Regression: an empty pop returned shape (0, 0), a broadcast footgun
-    for callers that concatenate or index columns. Width comes from the
-    first pushed chunk, or from ``n_in`` stamped at construction/submit."""
+    for callers that concatenate or index columns. Width (the packed
+    ``ceil(n_in/8)`` bytes a timestep) comes from the first pushed chunk,
+    or from ``n_in`` stamped at construction/submit."""
+    width = -(-CFG.n_in // 8)
     sess = StreamSession(sid=0, n_in=CFG.n_in)
-    assert sess.pop_chunk(4).shape == (0, CFG.n_in)
+    assert sess.pop_chunk(4).shape == (0, width)
     sess2 = StreamSession(sid=1)
     sess2.push_events(np.zeros((3, CFG.n_in), np.float32))
-    assert sess2.pop_chunk(8).shape == (3, CFG.n_in)
-    assert sess2.pop_chunk(8).shape == (0, CFG.n_in)     # drained: width kept
+    assert sess2.pop_chunk(8).shape == (3, width)
+    assert sess2.pop_chunk(8).shape == (0, width)        # drained: width kept
     with pytest.raises(ValueError, match="width"):
         sess2.push_events(np.zeros((2, CFG.n_in + 1), np.float32))
     # the scheduler stamps n_in at submit, so even a never-pushed session
@@ -323,7 +325,7 @@ def test_pop_chunk_empty_is_column_shaped():
     fresh = StreamSession(sid=2)
     sched.submit(fresh)
     assert fresh.n_in == CFG.n_in
-    assert fresh.pop_chunk(4).shape == (0, CFG.n_in)
+    assert fresh.pop_chunk(4).shape == (0, width)
     # a session whose learned width disagrees with the grid fails at submit,
     # not mid-step with a half-mutated grid
     wrong = StreamSession(sid=3)

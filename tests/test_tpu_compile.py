@@ -55,14 +55,24 @@ def _shapes(tree, sharding=None):
         tree)
 
 
-def _chunk_args(cfg, sharding=None):
+def _events(cfg, n_slots, packed=False, sharding=None):
+    """The chunk fn's events: float32 spikes, or the packed uint8 bit rows
+    the scheduler stages."""
+    if packed:
+        return jax.ShapeDtypeStruct((CHUNK_LEN, n_slots, -(-cfg.n_in // 8)),
+                                    jnp.uint8, sharding=sharding)
+    return jax.ShapeDtypeStruct((CHUNK_LEN, n_slots, cfg.n_in), jnp.float32,
+                                sharding=sharding)
+
+
+def _chunk_args(cfg, sharding=None, packed=False):
     """Shapes of ``chunk_fn(params, deltas, state, events, valid,
     adapt_mask)`` at ``cfg`` for the smoke's grid."""
     p = jax.eval_shape(lambda: snn.serving_params(
         snn.init_params(jax.random.PRNGKey(0), cfg), cfg))
     dl = jax.eval_shape(lambda: snn.init_stream_deltas(cfg, N_SLOTS))
     st = jax.eval_shape(lambda: snn.init_stream_state(cfg, N_SLOTS))
-    ev = jax.ShapeDtypeStruct((CHUNK_LEN, N_SLOTS, cfg.n_in), jnp.float32)
+    ev = _events(cfg, N_SLOTS, packed)
     va = jax.ShapeDtypeStruct((CHUNK_LEN, N_SLOTS), jnp.bool_)
     am = jax.ShapeDtypeStruct((N_SLOTS,), jnp.bool_)
     return tuple(_shapes(a, sharding) for a in (p, dl, st, ev, va, am))
@@ -79,6 +89,24 @@ def test_serving_chunk_fn_fits_one_chip(one_chip):
         *_chunk_args(CONFIG, one_chip)).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES, \
         compiled.memory_analysis()
+
+
+def test_packed_chunk_fn_fits_one_chip(one_chip):
+    """The program the scheduler runs, fed packed spike rows: its input
+    shrinks by the float32 grid less the packed one (1/32 of it), and the
+    grid widened inside the program takes that saving back: the program's
+    bytes stay within 0.1 % of the float32 program's."""
+    f32 = make_chunk_fn(CONFIG).lower(
+        *_chunk_args(CONFIG, one_chip)).compile().memory_analysis()
+    m = make_chunk_fn(CONFIG).lower(
+        *_chunk_args(CONFIG, one_chip, packed=True)).compile(
+        ).memory_analysis()
+    grid = CHUNK_LEN * N_SLOTS * CONFIG.n_in * 4
+    assert f32.argument_size_in_bytes - m.argument_size_in_bytes \
+        == grid - grid // 32, (f32, m)
+    total = lambda a: (a.argument_size_in_bytes + a.output_size_in_bytes
+                       + a.temp_size_in_bytes)
+    assert total(m) <= total(f32) + total(f32) // 1000, (f32, m)
 
 
 def test_training_step_fits_one_chip(one_chip):
@@ -159,11 +187,8 @@ def _collective_dims(text: str):
 LIVE_SLOTS = 4096                   # the live four-chip cell's fleet
 
 
-def test_live_chunk_fn_moves_no_slot_shard(topo):
-    """With live DSST on the 4-chip slot mesh, each chip reduces its own
-    slots' factors inside the step: the only collective left combines the
-    four [1, L, ·] partials, and none carries the slot extent (S or
-    S/4)."""
+def _live_chunk_fn(topo, packed):
+    """The live four-chip cell's chunk program, compiled."""
     from repro.launch import sharding
     mesh = Mesh(np.asarray(topo.devices[:4]), ("slots",))
     fn = make_chunk_fn(CONFIG, mesh=mesh, want_factors=True)
@@ -173,17 +198,35 @@ def test_live_chunk_fn_moves_no_slot_shard(topo):
     dl = jax.eval_shape(lambda: snn.init_stream_deltas(CONFIG, LIVE_SLOTS))
     st = jax.eval_shape(lambda: snn.init_stream_state(CONFIG, LIVE_SLOTS))
     col = sharding.slot_sharding(mesh, 1)
-    compiled = fn.lower(
+    return fn.lower(
         _shapes(p, rep), _shapes(dl, slot), _shapes(st, slot),
-        jax.ShapeDtypeStruct((CHUNK_LEN, LIVE_SLOTS, CONFIG.n_in),
-                             jnp.float32, sharding=col),
+        _events(CONFIG, LIVE_SLOTS, packed, col),
         jax.ShapeDtypeStruct((CHUNK_LEN, LIVE_SLOTS), jnp.bool_,
                              sharding=col),
         jax.ShapeDtypeStruct((LIVE_SLOTS,), jnp.bool_,
                              sharding=slot)).compile()
+
+
+def test_live_chunk_fn_moves_no_slot_shard(topo):
+    """With live DSST on the 4-chip slot mesh, each chip reduces its own
+    slots' factors inside the step: the only collective left combines the
+    four [1, L, ·] partials, and none carries the slot extent (S or
+    S/4)."""
+    compiled = _live_chunk_fn(topo, packed=False)
     dims = _collective_dims(compiled.as_text())
     assert not dims & {LIVE_SLOTS, LIVE_SLOTS // 4}, sorted(dims)
     assert 0 < _device_bytes(compiled) < HBM_BYTES
+
+
+def test_live_packed_chunk_fn_moves_no_slot_shard(topo):
+    """The same with the packed spike rows the scheduler stages: each chip
+    widens its own shard, so no collective appears for the events, and
+    the program needs no more device bytes than the float32 one."""
+    compiled = _live_chunk_fn(topo, packed=True)
+    dims = _collective_dims(compiled.as_text())
+    assert not dims & {LIVE_SLOTS, LIVE_SLOTS // 4}, sorted(dims)
+    f32 = _live_chunk_fn(topo, packed=False)
+    assert 0 < _device_bytes(compiled) <= _device_bytes(f32)
 
 
 @pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "mesh4"])
