@@ -1,40 +1,13 @@
-"""Per-kernel structural benchmarks: VMEM working sets, grid work, HBM
-traffic from the BlockSpec schedules (the TPU-honest numbers; wall-clock of
+"""Per-kernel structural benchmarks: HBM traffic of the flash-attention
+kernel from its BlockSpec schedule (the TPU-honest numbers; wall-clock of
 interpret mode is meaningless). Correctness itself is pytest's job."""
 from __future__ import annotations
 
 from repro.kernels.flash_attn.ops import hbm_bytes, xla_score_path_bytes
 
-VMEM_BYTES = 128 * 1024 * 1024        # v5e per-core VMEM
-
 
 def run(quick: bool = True):
     rows = []
-
-    # nm_spmm: tile work and VMEM at the production shape (d_model 8192 x
-    # d_ff tile, 2:8 over 128-blocks)
-    bm = bk = bo = 128
-    k, o, n, m = 8192, 8192, 2, 8
-    tiles_dense = (k // bk) * (o // bo)
-    tiles_sparse = tiles_dense * n // m
-    vmem = (bm * bk + bk * bo + bm * bo * 4 // 2) * 2   # x + w + f32 acc
-    rows.append({"name": "kernels/nm_spmm_8192", "us_per_call": 0.0,
-                 "derived": (f"tiles={tiles_sparse}/{tiles_dense};"
-                             f"vmem_per_step_B={vmem};"
-                             f"fits_vmem={vmem < VMEM_BYTES}")})
-
-    # lif: one fused pass vs 4 unfused elementwise round trips
-    bn = 512 * 512
-    rows.append({"name": "kernels/lif_fused", "us_per_call": 0.0,
-                 "derived": (f"hbm_bytes_fused={3*bn*4 + 3*bn*4};"
-                             f"hbm_bytes_unfused={4*2*3*bn*4};"
-                             f"traffic_cut={1 - (6*bn*4)/(24*bn*4):.2f}")})
-
-    # wu_outer: update bytes scale with density (compact layout only)
-    dense_up = 512 * 512 * 4
-    sparse_up = dense_up * 2 // 8
-    rows.append({"name": "kernels/wu_outer_sparse_updates", "us_per_call": 0.0,
-                 "derived": f"bytes_written={sparse_up}/{dense_up} (n:m=2:8)"})
 
     # flash attention: BlockSpec-exact traffic vs unfused score path at the
     # deepseek train cell's per-device slice

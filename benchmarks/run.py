@@ -16,7 +16,6 @@ Modules:
             incl. live-topology-evolution vs frozen baseline and the
             hot-path A/B (the module's --evolve / --pipeline / --factors
             CLI modes run the focused sweeps; --dryrun lists them)
-  backend — engine backend seam: ref vs pallas-interpret step + parity
   roofline — per-(arch×shape×mesh) terms from dry-run artifacts (if present)
 
 ``--dryrun`` only verifies every module imports and registers a ``run``
@@ -87,16 +86,15 @@ def main() -> None:
     use_persistent_cache(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
 
-    from . import (bench_backend, bench_fig3_serdes, bench_fig4_ossl,
-                   bench_fig5_dsst, bench_fig6_datapath, bench_fig7_tasks,
-                   bench_kernels, bench_serving_streams, bench_table1,
-                   roofline)
+    from . import (bench_fig3_serdes, bench_fig4_ossl, bench_fig5_dsst,
+                   bench_fig6_datapath, bench_fig7_tasks, bench_kernels,
+                   bench_serving_streams, bench_table1, roofline)
     modules = {
         "fig3": bench_fig3_serdes, "fig4": bench_fig4_ossl,
         "fig5": bench_fig5_dsst, "fig6": bench_fig6_datapath,
         "fig7": bench_fig7_tasks, "table1": bench_table1,
         "kernels": bench_kernels, "serving": bench_serving_streams,
-        "backend": bench_backend, "roofline": roofline,
+        "roofline": roofline,
     }
     if args.only:
         keep = set(args.only.split(","))

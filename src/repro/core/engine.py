@@ -17,44 +17,23 @@ Two structural decisions:
   Trace size and compile time no longer multiply with depth — the Fig. 7
   depth study and the ROADMAP's sharded-slot-grid work both need this.
 
-* **Backend seam.**  ``SNNConfig.backend`` selects how the three inner ops
-  (forward current, fused LIF step, WU outer product) are computed, and
-  :func:`make_backend` is the only place that decides — never the platform
-  the program happens to run on:
-
-  - ``"ref"``             — pure jnp (default), lowered by XLA for whatever
-                            device runs it (the TPU's own XLA on a chip, not
-                            a CPU fallback). Training uses dense masked
-                            weights; a compact serving rep goes through the
-                            jnp ``nm_spmm`` reference;
-  - ``"pallas"``          — the compiled Mosaic kernels of ``kernels/nm_spmm``,
-                            ``kernels/lif`` and ``kernels/wu_outer``
-                            (``interpret=False``). The compact N:M layout
-                            (values + block indices) is built from the mask
-                            at scan entry and carried through the time scan —
-                            training updates land directly in compact storage
-                            via ``wu_outer`` and are densified once per
-                            sample. Only N:M specs whose ``block`` and
-                            ``out_tile`` are multiples of 128 tile onto the
-                            kernels; :func:`make_backend` refuses any other
-                            (the paper's element spec among them);
-  - ``"pallas-interpret"`` — same routing in Pallas interpret mode, the CPU
-                            correctness mode for kernel parity (tests only;
-                            interpret mode does not enforce the tiling).
+* **One implementation per op.**  The inner ops (forward current, LIF
+  step, WU outer product) are jnp, lowered by XLA for whatever device runs
+  them; a Pallas kernel returns only when it wins a cell, and is then
+  chosen from the N:M spec, never from a user option.
 """
 from __future__ import annotations
 
-import dataclasses
 from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..kernels.nm_spmm import ops as nm_ops, ref as nm_ref
+from ..kernels.wu_outer import ref as wu_ref
 from . import gating as gating_lib
 from . import topology as topology_lib
-
-BACKENDS = ("ref", "pallas", "pallas-interpret")
 
 
 # ---------------------------------------------------------------------------
@@ -125,7 +104,7 @@ class Geometry(NamedTuple):
 def geometry(cfg) -> Geometry:
     """Static layer-stack geometry: per-layer fan-ins, the zero-padded
     stack width ``k_max = max(fanins)``, and whether all layers share one
-    fan-in (which unlocks the vmapped/kernel fast paths)."""
+    fan-in (which unlocks the compact N:M layout)."""
     fanins = tuple(cfg.layer_fanins)
     k_max = max(fanins)
     uniform = len(set(fanins)) == 1
@@ -137,8 +116,16 @@ _pad_rows = topology_lib._pad_rows
 
 
 def _pad_cols(x, k):
+    """Zero-pad the last axis of ``x`` to the stack width ``k``.
+
+    A one-layer stack with ``n_hidden > n_in`` is the one case where a
+    layer's output is wider than ``k``; its last layer's carry is never
+    read, so it is cropped to keep the layer scan's carry shape fixed.
+    """
     if x.shape[-1] == k:
         return x
+    if x.shape[-1] > k:
+        return x[..., :k]
     return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, k - x.shape[-1]),))
 
 
@@ -187,69 +174,8 @@ def unstack_params(params, cfg):
 
 
 # ---------------------------------------------------------------------------
-# backend seam
+# weight layouts and the three inner ops
 # ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class Backend:
-    name: str
-    use_kernels: bool     # route through kernels/{nm_spmm,lif,wu_outer}
-    interpret: bool       # ...in Pallas interpret mode (tests)
-
-
-# Mosaic tiles a block's last two dims in multiples of (8, 128) unless a
-# dim spans the whole array; the nm_spmm x/out blocks are (rows, block) and
-# (rows, out_tile), wu_outer's (rows, block) and (rows, out_tile).
-KERNEL_LANE = 128
-
-
-def make_backend(cfg) -> Backend:
-    """Resolve ``cfg.backend`` ("ref" | "pallas" | "pallas-interpret") to
-    the engine's static :class:`Backend` dispatch record.
-
-    ``"pallas"`` is refused with a ``ValueError`` when the config's N:M
-    spec cannot tile onto the compiled kernels (``block`` or ``out_tile``
-    not a multiple of 128) — here, rather than deep in Mosaic lowering.
-    """
-    name = getattr(cfg, "backend", "ref")
-    if name == "ref":
-        return Backend("ref", False, False)
-    if name == "pallas":
-        for fan_in in sorted(set(cfg.layer_fanins)):
-            spec = cfg.spec(fan_in)
-            if spec.block % KERNEL_LANE or spec.out_tile % KERNEL_LANE:
-                raise ValueError(
-                    f"backend='pallas' needs an N:M spec whose block and "
-                    f"out_tile are multiples of {KERNEL_LANE} to tile onto "
-                    f"the compiled kernels; fan-in {fan_in} has "
-                    f"block={spec.block}, out_tile={spec.out_tile}. Use "
-                    f"backend='ref' (XLA on the same device) for this spec")
-        return Backend("pallas", True, False)
-    if name == "pallas-interpret":
-        return Backend("pallas-interpret", True, True)
-    raise ValueError(f"unknown backend {name!r}; expected one of {BACKENDS}")
-
-
-def prepare_weights(w_stacked, mask_stacked, cfg, backend: Backend, *,
-                    include_mask: bool = False):
-    """Weight representation carried through the time scan.
-
-    The rep is a dict whose *keys* drive dispatch downstream
-    (``"wc" in w_l`` → compact): ``ref`` carries the dense stacked weights
-    plus the dense float mask (``{"w", "mask_f"}`` — the mask is part of the
-    weight rep, not a separate scan input); kernel backends carry the
-    compact N:M layout (values ``[L, J, T, bk, bo]`` + block ids
-    ``[L, J, T]``), the chip's value/index SRAM pair, with the dense mask
-    added only when ``include_mask`` (dense-delta serving still scatters
-    its WU through it).
-    """
-    if not backend.use_kernels:
-        return {"w": w_stacked, "mask_f": dense_masks(mask_stacked, cfg)}
-    wrep = compact_weights(w_stacked, mask_stacked, cfg)
-    if include_mask:
-        wrep["mask_f"] = dense_masks(mask_stacked, cfg)
-    return wrep
-
 
 def compact_weights(w_stacked, mask_stacked, cfg):
     """Stacked dense weights + unit masks -> ``{"wc", "idx"}`` compact rep.
@@ -263,7 +189,6 @@ def compact_weights(w_stacked, mask_stacked, cfg):
         raise ValueError(
             "the compact N:M layout requires uniform layer fan-in "
             f"(got {geo.fanins}); use the dense rep / dense deltas instead")
-    from repro.kernels.nm_spmm import ops as nm_ops
     spec = cfg.spec(geo.fanins[0])
     wcs, idxs = [], []
     for l in range(cfg.n_layers):
@@ -308,38 +233,23 @@ def compact_kept(cfg) -> int:
     return (kb // spec.m) * spec.n
 
 
-def finalize_weights(wrep, cfg, backend: Backend) -> jax.Array:
-    """Back to dense stacked ``[L, Kmax, N]`` after the time scan."""
-    if not backend.use_kernels:
-        return wrep["w"]
-    from repro.kernels.nm_spmm import ref as nm_ref
-    geo = geometry(cfg)
-    return jnp.stack([nm_ref.densify(wrep["wc"][l], wrep["idx"][l], geo.k_max)
-                      for l in range(cfg.n_layers)])
-
-
-def fwd_current(backend: Backend, pre, w_l, delta_l):
+def fwd_current(pre, w_l, delta_l):
     """Forward synaptic current for one layer: ``pre @ w`` (+ slot deltas).
 
-    Dispatch is on the weight rep's keys: a compact rep (``"wc"``) goes
-    through the ``nm_spmm`` kernel under a kernel backend and through its
-    jnp reference under ``"ref"``, and compact per-slot deltas (rank 5:
+    Dispatch is on the weight rep's keys: the dense rep (``{"w",
+    "mask_f"}``) is a plain matmul, the compact rep (``"wc"``) goes
+    through ``nm_spmm``, and compact per-slot deltas (rank 5:
     ``[S, J, T, bk, bo]``) contract through ``nm_spmm_deltas`` on the same
-    kept-block ids — no dense ``[K, N]`` tensor exists anywhere on this
-    path. The base current runs under the named scope ``si_base``, the
-    per-slot delta current under ``si_delta``.
+    kept-block ids — no dense ``[K, N]`` tensor exists anywhere on the
+    compact path. The base current runs under the named scope ``si_base``,
+    the per-slot delta current under ``si_delta``.
     """
     compact = "wc" in w_l
-    if compact:
-        from repro.kernels.nm_spmm import ops as nm_ops, ref as nm_ref
     with jax.named_scope("si_base"):
-        if not compact:
-            cur = pre @ w_l["w"]
-        elif backend.use_kernels:
-            cur = nm_ops.nm_spmm_batched(pre, w_l["wc"], w_l["idx"],
-                                         interpret=backend.interpret)
-        else:
+        if compact:
             cur = nm_ref.nm_spmm(pre, w_l["wc"], w_l["idx"])
+        else:
+            cur = pre @ w_l["w"]
     if delta_l is None:
         return cur
     with jax.named_scope("si_delta"):
@@ -348,31 +258,9 @@ def fwd_current(backend: Backend, pre, w_l, delta_l):
         return cur + jnp.einsum("sk,skn->sn", pre, delta_l)
 
 
-def lif(backend: Backend, cfg, v, tr, current):
-    """One fused LIF step (``lif_step`` semantics) through the backend
-    seam; ``v``/``tr``/``current`` are ``[R, N]``. Returns (v', tr', s)."""
-    if backend.use_kernels:
-        from repro.kernels.lif import ops as lif_ops
-        return lif_ops.lif_step(v, tr, current, alpha=cfg.alpha,
-                                beta=cfg.beta, theta=cfg.theta,
-                                interpret=backend.interpret)
-    return lif_step(v, tr, current, alpha=cfg.alpha, beta=cfg.beta,
-                    theta=cfg.theta)
-
-
-def train_wu(backend: Backend, cfg, w_l, pre_trace, mod, scale):
-    """Gated three-factor WU into the base weights (training path).
-
-    The sparsity pattern comes from the weight rep itself: kept block ids
-    for the compact rep, the dense float mask (``w_l["mask_f"]``) for ref.
-    """
-    if "wc" in w_l:
-        from repro.kernels.wu_outer import ops as wu_ops
-        spec = cfg.spec(cfg.layer_fanins[0])
-        dwc = wu_ops.wu_outer(pre_trace, mod, w_l["idx"], scale,
-                              bk=spec.block, bo=spec.out_tile,
-                              interpret=backend.interpret)
-        return {**w_l, "wc": w_l["wc"] + dwc}
+def train_wu(w_l, pre_trace, mod, scale):
+    """Gated three-factor WU into the dense base weights (training path);
+    the sparsity pattern is the rep's own dense float mask ``mask_f``."""
     dw = scale * (pre_trace.T @ mod)
     return {**w_l, "w": w_l["w"] + dw * w_l["mask_f"]}
 
@@ -388,7 +276,7 @@ class LayerSlice(NamedTuple):
     weight rep ``w`` (kept block ids for compact, ``mask_f`` for dense), so
     a compact serving trace never holds a dense mask at all.
     """
-    w: Any                                # weight rep (see prepare_weights)
+    w: Any                                # weight rep: {w, mask_f} | {wc, idx}
     readout: jax.Array                    # [N, n_out] bypass readout
     st: LayerState                        # leaves [R, N]
     ss_mean: jax.Array                    # [] (train) or [S] (serve)
@@ -424,9 +312,9 @@ class LayerOut(NamedTuple):
     post_mag: Optional[jax.Array]         # [S, N] |OSSL modulator|, valid-masked
 
 
-def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
-                    serving: bool, factors: bool, t_pc: int, t_wu: int,
-                    t_row, valid, carry: LayerCarry, xs: LayerSlice
+def _layer_timestep(cfg, geo: Geometry, learn: bool, serving: bool,
+                    factors: bool, t_pc: int, t_wu: int, t_row, valid,
+                    carry: LayerCarry, xs: LayerSlice
                     ) -> Tuple[LayerCarry, LayerOut]:
     """SI + gated WU for ONE layer at ONE timestep — training and serving.
 
@@ -451,9 +339,10 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
     st, pre, pre_tr = xs.st, carry.pre_spikes, carry.pre_trace
     col = (lambda c: c[:, None]) if serving else (lambda c: c)
 
-    current = fwd_current(backend, pre, xs.w, xs.delta)
+    current = fwd_current(pre, xs.w, xs.delta)
     with jax.named_scope("lif"):
-        v, tr, s = lif(backend, cfg, st.v, st.tr, current)
+        v, tr, s = lif_step(st.v, st.tr, current, alpha=cfg.alpha,
+                            beta=cfg.beta, theta=cfg.theta)
         tr_pc = jnp.where(col(t_row == t_pc), tr, st.tr_pc)
 
     # ---- OSSL three-factor WU, gated, concurrent with SI ----
@@ -479,7 +368,6 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
                 # compact per-slot WU: the outer product lands only in kept
                 # blocks — sparse in compute AND storage (the paper's
                 # activity-dependent sparse WU)
-                from repro.kernels.wu_outer import ref as wu_ref
                 spec = cfg.spec(geo.fanins[0])
                 scale = jnp.where(wu_on, cfg.lr, 0.0)
                 delta_new = xs.delta + wu_ref.wu_outer_slots(
@@ -502,7 +390,7 @@ def _layer_timestep(cfg, backend: Backend, geo: Geometry, learn: bool,
     else:
         with jax.named_scope("wu_base"):
             scale = jnp.where(wu_on, cfg.lr / pre.shape[0], 0.0)
-            w_new = train_wu(backend, cfg, xs.w, pre_tr, mod, scale)
+            w_new = train_wu(xs.w, pre_tr, mod, scale)
         delta_new = None
         opened_new = xs.gate_opened + open_.astype(jnp.float32)
         offered_new = xs.gate_offered + 1.0
@@ -557,7 +445,7 @@ def _windows(cfg) -> Tuple[int, int]:
 # ---------------------------------------------------------------------------
 
 def scan_sample(wrep, readout, layers: LayerState, x_tr, gate,
-                events, cfg, backend: Backend, learn: bool):
+                events, cfg, learn: bool):
     """T aligned timesteps over the layer stack (training datapath).
 
     Returns (wrep', layers', x_tr', gate', outs) with per-timestep outs.
@@ -565,8 +453,8 @@ def scan_sample(wrep, readout, layers: LayerState, x_tr, gate,
     geo = geometry(cfg)
     t_pc, t_wu = _windows(cfg)
     fan, dens = _layer_arrays(cfg)
-    body = partial(_layer_timestep, cfg, backend, geo, learn, False, False,
-                   t_pc, t_wu)
+    body = partial(_layer_timestep, cfg, geo, learn, False, False, t_pc,
+                   t_wu)
 
     def ts(carry, inp):
         t, x = inp["t"], inp["x"]
@@ -600,8 +488,8 @@ def scan_sample(wrep, readout, layers: LayerState, x_tr, gate,
 
 
 def scan_chunk(wrep, readout, deltas, layers: LayerState, x_tr,
-               ss_mean, t_win, samp, events, valid, cfg, backend: Backend,
-               learn: bool, want_factors: bool = True):
+               ss_mean, t_win, samp, events, valid, cfg, learn: bool,
+               want_factors: bool = True):
     """Up to C timesteps of S independent streams (serving datapath).
 
     Engine layout: layer axis leading on ``layers``/``deltas``/``ss_mean``
@@ -624,8 +512,8 @@ def scan_chunk(wrep, readout, deltas, layers: LayerState, x_tr,
     geo = geometry(cfg)
     t_pc, t_wu = _windows(cfg)
     fan, dens = _layer_arrays(cfg)
-    body = partial(_layer_timestep, cfg, backend, geo, learn, True,
-                   want_factors, t_pc, t_wu)
+    body = partial(_layer_timestep, cfg, geo, learn, True, want_factors,
+                   t_pc, t_wu)
 
     def ts(carry, inp):
         layers, x_tr, ss_mean, t_w, samp, dls, *acc = carry

@@ -21,8 +21,8 @@ Implements the chip of Fig. 2 as a pure-JAX simulator:
 
 The per-timestep datapath lives in **core/engine.py** — one layer-stacked
 ``layer_timestep`` scanned over a ``[L, ...]`` layer axis, shared by the
-training path (:func:`run_sample`) and the serving path (:func:`run_chunk`),
-with a pluggable ``ref``/``pallas`` backend seam. This module owns the
+training path (:func:`run_sample`) and the serving path (:func:`run_chunk`):
+one jnp implementation of each op, no backend option. This module owns the
 network-level layouts and the per-sample bookkeeping around that engine:
 parameter/state initialisation, the SL readout delta rule, DSST events, and
 the CC-slot roll.
@@ -84,11 +84,6 @@ class SNNConfig:
     dsst_enabled: bool = True  # False = static sparse training baseline
     # gating
     gating: gating_lib.GatingConfig = dataclasses.field(default_factory=gating_lib.GatingConfig)
-    # compute backend for the timestep engine (core/engine.make_backend):
-    # "ref" (jnp, XLA on whatever device runs it), "pallas" (the compiled
-    # Mosaic kernels; needs a 128-aligned N:M spec), "pallas-interpret"
-    # (the kernels in interpret mode — the CPU parity mode for tests).
-    backend: str = "ref"
 
     def spec(self, fan_in: int) -> NMSpec:
         if self.dense:
@@ -175,15 +170,15 @@ def run_sample(
     learn: bool = True,
 ) -> Tuple[Dict[str, Any], NetState, SampleMetrics]:
     T, B, _ = events.shape
-    backend = engine.make_backend(cfg)
     t_wu = int(cfg.t_steps * cfg.wu_start_frac)
     masks = params["hidden"]["mask"]
-    wrep = engine.prepare_weights(params["hidden"]["w"], masks, cfg, backend)
+    wrep = {"w": params["hidden"]["w"],
+            "mask_f": engine.dense_masks(masks, cfg)}
 
     wrep, layers, x_tr, gate_st, outs = engine.scan_sample(
         wrep, params["readout"], state.layers, state.x_tr,
-        state.gate, events, cfg, backend, learn)
-    w_stacked = engine.finalize_weights(wrep, cfg, backend)
+        state.gate, events, cfg, learn)
+    w_stacked = wrep["w"]
 
     logits = outs["logits"][-1]
 
@@ -413,7 +408,6 @@ def run_chunk(
     Returns ``(deltas', state', metrics)``: same shapes/dtypes in and out,
     so the caller can jit once and stream forever.
     """
-    backend = engine.make_backend(cfg)
     compact = deltas.ndim == 6
     if "wc" in params:               # mask-free serving rep
         if not compact:
@@ -427,15 +421,15 @@ def run_chunk(
         if compact:
             wrep = engine.compact_weights(params["hidden"]["w"], masks, cfg)
         else:
-            wrep = engine.prepare_weights(params["hidden"]["w"], masks, cfg,
-                                          backend, include_mask=True)
+            wrep = {"w": params["hidden"]["w"],
+                    "mask_f": engine.dense_masks(masks, cfg)}
 
     (layers, x_tr, ss_mean, t_win, samp, dls, *accs), outs = \
         engine.scan_chunk(
             wrep, params["readout"], _to_engine(deltas),
             _to_engine(state.layers), state.x_tr, state.ss_mean.T,
-            state.t_in_window, state.sample_idx, events, valid, cfg, backend,
-            learn, want_factors)
+            state.t_in_window, state.sample_idx, events, valid, cfg, learn,
+            want_factors)
 
     new_state = StreamState(layers=_to_engine(layers), x_tr=x_tr,
                             ss_mean=ss_mean.T, t_in_window=t_win,

@@ -1,22 +1,17 @@
-"""Pallas TPU kernels for ElfCore's compute hot-spots.
+"""Compute ops of ElfCore's hot spots, and the LM path's Pallas kernel.
 
-Each kernel ships as a triple (DESIGN.md §7):
+The SNN engine (``core/engine.py``) runs jnp ops, lowered by XLA for the
+device that runs them:
 
-* ``<name>/kernel.py`` — ``pl.pallas_call`` + explicit BlockSpec VMEM tiling,
-  written for TPU (MXU-aligned tiles, scalar-prefetched index tables) and
-  validated in ``interpret=True`` mode on CPU;
-* ``<name>/ops.py``    — the public wrapper (padding, custom_vjp) around the
-  kernel, with an ``interpret`` flag; whether a kernel runs at all is
-  decided by the engine's backend seam (``core.engine.make_backend``);
-* ``<name>/ref.py``    — the pure-jnp oracle the tests sweep against.
+* :mod:`repro.kernels.nm_spmm`  — block-N:M sparse matmul on the compact
+  layout (``ops.make_compact`` builds it; ``ref.nm_spmm`` is the base
+  current, ``ref.nm_spmm_deltas`` the per-slot delta current).
+* :mod:`repro.kernels.wu_outer` — gated three-factor sparse weight update
+  per slot on the compact layout (the WU engine of Fig. 2).
 
-Kernels:
+No Pallas kernel is on the SNN path. One returns only when it wins a
+benchmark cell, chosen from the N:M spec, never from a user option.
 
-* :mod:`repro.kernels.nm_spmm`  — block-N:M sparse matmul (input-stationary
-  forward path of Fig. 6, adapted from the chip's 4 parallel PEs to MXU
-  tiles gathered by a scalar-prefetched block-index table).
-* :mod:`repro.kernels.lif`      — fused LIF membrane + threshold/reset +
-  trace decay (one HBM round-trip for the whole neuron update).
-* :mod:`repro.kernels.wu_outer` — gated three-factor sparse weight update on
-  the compact N:M layout (the WU engine of Fig. 2).
+* :mod:`repro.kernels.flash_attn` — Pallas flash attention for the LM
+  stack (``kernel.py`` + ``ops.py`` wrapper + ``ref.py`` oracle).
 """

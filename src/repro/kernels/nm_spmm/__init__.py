@@ -1,1 +1,1 @@
-from . import kernel, ops, ref  # noqa: F401
+from . import ops, ref  # noqa: F401

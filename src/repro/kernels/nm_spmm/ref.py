@@ -1,6 +1,6 @@
-"""Pure-jnp oracle for the block-N:M sparse matmul.
+"""The block-N:M sparse matmul in jnp: the engine's compact forward ops.
 
-Layouts (shared with kernel.py / ops.py):
+Layouts (``ops.make_compact`` builds them):
 
 * ``x``         : [B, K] activations (B = flattened batch·seq rows).
 * ``w_compact`` : [J, T, bk, bo] — for each of J output tiles (bo columns),

@@ -1,6 +1,6 @@
-"""Oracle for the gated three-factor sparse weight update (WU engine).
+"""The gated three-factor sparse weight update (WU engine), per slot.
 
-``dw_compact[j, t] = scale · pre[:, idx[j,t]].T @ mod[:, j·bo:(j+1)·bo]``
+``dw[s, j, t] = scale[s] · pre[s, idx[j,t]] ⊗ mod[s, j·bo:(j+1)·bo]``
 
 i.e. the outer-product update is computed **only for materialised blocks**,
 on the compact layout — the chip never touches pruned synapses. ``scale``
@@ -13,23 +13,12 @@ import jax
 import jax.numpy as jnp
 
 
-def wu_outer(pre: jax.Array, mod: jax.Array, idx: jax.Array, scale: jax.Array,
-             bk: int, bo: int) -> jax.Array:
-    b, k = pre.shape
-    j, t = idx.shape
-    preb = pre.reshape(b, k // bk, bk)
-    pg = preb[:, idx, :]                                    # [B, J, T, bk]
-    modt = mod.reshape(b, j, bo)
-    return scale * jnp.einsum("bjtk,bjo->jtko", pg, modt)
-
-
 def wu_outer_slots(pre: jax.Array, mod: jax.Array, idx: jax.Array,
                    scale: jax.Array, bk: int, bo: int) -> jax.Array:
     """Per-slot compact outer-product update ``[S, J, T, bk, bo]``.
 
-    Unlike ``wu_outer`` (which batch-sums into one shared ``dw_compact``,
-    the training shape), every slot keeps its own update — the serving
-    per-stream delta rule. ``scale [S]`` carries the per-slot gate×lr.
+    Every slot keeps its own update — the serving per-stream delta rule.
+    ``scale [S]`` carries the per-slot gate×lr.
 
     The multiply association mirrors the dense serving rule
     ``(scale · pre) · mod`` elementwise, so at every kept coordinate the

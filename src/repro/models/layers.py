@@ -12,7 +12,7 @@ Sparse linear parameter forms (configs.SparsityConfig.mode):
              The pattern is shared across output columns (J=1 — the
              coarsest point on the paper's mask-diversity/efficiency
              trade-off, Fig. 5 middle); per-out-tile diversity lives in the
-             Pallas kernel path (kernels/nm_spmm).
+             SNN's compact layout (kernels/nm_spmm).
 """
 from __future__ import annotations
 

@@ -131,6 +131,44 @@ def test_compact_weights_match_forward():
     np.testing.assert_allclose(np.asarray(y_c), np.asarray(y_d), atol=1e-6)
 
 
+GEOMETRIES = {"uniform": {}, "fanin24": dict(n_in=24),
+              "dense": dict(dense=True)}
+
+
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_forward_current_parity(geometry):
+    """engine.fwd_current on every weight rep the geometry allows — dense
+    ``{w, mask_f}`` always, compact ``{wc, idx}`` where fan-ins are uniform
+    — equals ``pre @ w``, and with per-slot deltas (dense, or compact on
+    the compact rep) ``pre[s] @ (w + delta[s])``."""
+    cfg = dataclasses.replace(CFG, **GEOMETRIES[geometry])
+    geo = engine.geometry(cfg)
+    params = _params(0, cfg)
+    w, mask = params["hidden"]["w"], params["hidden"]["mask"]
+    dm = engine.dense_masks(mask, cfg)
+    S = 5
+    r = np.random.default_rng(1)
+    pre = jnp.asarray(r.standard_normal((S, geo.k_max)).astype(np.float32))
+    deltas = jnp.asarray(r.standard_normal(
+        (S,) + dm.shape).astype(np.float32)) * dm[None]  # [S, L, Kmax, N]
+    reps = {"dense": ({"w": w, "mask_f": dm}, deltas)}
+    if geo.uniform:
+        idx = topology.stacked_kept_ids(mask, cfg)
+        reps["compact"] = (engine.compact_weights(w, mask, cfg),
+                           engine.compact_deltas(deltas, idx, cfg))
+    assert ("compact" in reps) == (geometry != "fanin24")
+    for name, (wrep, dl) in reps.items():
+        for l in range(cfg.n_layers):
+            w_l = jax.tree_util.tree_map(lambda a: a[l], wrep)
+            np.testing.assert_allclose(
+                np.asarray(engine.fwd_current(pre, w_l, None)),
+                np.asarray(pre @ w[l]), atol=1e-5, err_msg=name)
+            want = jnp.einsum("sk,skn->sn", pre, w[l][None] + deltas[:, l])
+            np.testing.assert_allclose(
+                np.asarray(engine.fwd_current(pre, w_l, dl[:, l])),
+                np.asarray(want), atol=1e-5, err_msg=name)
+
+
 # ----------------------------------------------------- projection bitwise
 
 @settings(max_examples=10, deadline=None)
@@ -200,10 +238,12 @@ def test_serving_jaxpr_has_no_dense_mask_or_dense_deltas():
                                                   "no_dense_deltas"}
 
 
-def test_dense_baseline_still_runs_and_matches():
+@pytest.mark.parametrize("dense", [False, True])
+def test_dense_baseline_still_runs_and_matches(dense):
     """The dense path stays selectable (compact=False) and the two layouts
-    track each other at the repo's trajectory tolerance."""
-    cfg = CFG
+    track each other at the repo's trajectory tolerance — on the N:M net
+    and on the dense baseline net (``dense=True``)."""
+    cfg = dataclasses.replace(CFG, dense=dense)
     S, C = 4, 8
     params = _params(0, cfg)
     ev = _events(1, C, S, cfg)
@@ -224,13 +264,14 @@ def test_dense_baseline_still_runs_and_matches():
 
 # ------------------------------------------------------------- WU bitwise
 
-def test_wu_outer_slots_bitwise_vs_dense_at_kept_coords():
+@pytest.mark.parametrize("n_in,n_hidden", [(32, 32), (32, 64), (64, 32)])
+def test_wu_outer_slots_bitwise_vs_dense_at_kept_coords(n_in, n_hidden):
     """One WU step: the compact scatter == the dense masked outer product,
-    bitwise, because the multiply association is mirrored."""
-    cfg = CFG
+    bitwise, because the multiply association is mirrored — also where
+    the layer is not square."""
+    cfg = dataclasses.replace(CFG, n_in=n_in, n_hidden=n_hidden, n_layers=1)
     spec = cfg.spec(cfg.n_in)
     params = _params(4, cfg)
-    mask = params["hidden"]["mask"][0]
     idx = topology.stacked_kept_ids(params["hidden"]["mask"], cfg)[0]
     dm = np.asarray(topology.dense_masks(params["hidden"]["mask"], cfg)[0])
 
@@ -243,8 +284,7 @@ def test_wu_outer_slots_bitwise_vs_dense_at_kept_coords():
     dwc = wu_ref.wu_outer_slots(pre, mod, idx, scale, spec.block,
                                 spec.out_tile)
     dense = (scale[:, None] * pre)[:, :, None] * mod[:, None, :] * dm[None]
-    got = engine.densify_deltas(
-        dwc[:, None], idx[None], dataclasses.replace(cfg, n_layers=1))[:, 0]
+    got = engine.densify_deltas(dwc[:, None], idx[None], cfg)[:, 0]
     np.testing.assert_array_equal(np.asarray(got), np.asarray(dense))
 
 

@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+"""Compact N:M ops (nm_spmm, nm_spmm_deltas, wu_outer_slots) against dense
+references, and the flash-attention kernel (Pallas interpret=True) vs its
+oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,11 +8,7 @@ import pytest
 
 from repro.core import sparsity as sp
 from repro.kernels.nm_spmm import ops as nm_ops, ref as nm_ref
-from repro.kernels.nm_spmm.kernel import nm_spmm_pallas
-from repro.kernels.lif import ops as lif_ops, ref as lif_ref
-from repro.kernels.lif.kernel import lif_pallas
 from repro.kernels.wu_outer import ref as wu_ref
-from repro.kernels.wu_outer.kernel import wu_outer_pallas
 
 
 def _mk_sparse(seed, k, o, bk, bo, n, m, dtype):
@@ -24,101 +22,86 @@ def _mk_sparse(seed, k, o, bk, bo, n, m, dtype):
 
 
 NM_CASES = [
-    # (k, o, bk, bo, n, m, bm)
-    (32, 16, 4, 8, 2, 4, 8),
-    (64, 32, 8, 16, 1, 2, 16),
-    (128, 128, 16, 32, 2, 8, 8),
-    (48, 24, 4, 8, 3, 4, 4),
+    # (k, o, bk, bo, n, m)
+    (32, 16, 4, 8, 2, 4),
+    (64, 32, 8, 16, 1, 2),
+    (128, 128, 16, 32, 2, 8),
+    (48, 24, 4, 8, 3, 4),
 ]
 
 
-@pytest.mark.parametrize("k,o,bk,bo,n,m,bm", NM_CASES)
+@pytest.mark.parametrize("k,o,bk,bo,n,m", NM_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_nm_spmm_kernel_vs_refs(k, o, bk, bo, n, m, bm, dtype):
+def test_nm_spmm_vs_dense(k, o, bk, bo, n, m, dtype):
     x, w, wc, idx, mask, spec = _mk_sparse(0, k, o, bk, bo, n, m, dtype)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
-    y_k = nm_spmm_pallas(x, wc, idx, bm=bm, interpret=True)
     y_r = nm_ref.nm_spmm(x, wc, idx)
     y_d = nm_ref.nm_spmm_dense_ref(x, wc, idx)
     y_m = x @ sp.apply_mask(w, mask, spec)
-    np.testing.assert_allclose(np.asarray(y_k, np.float32), np.asarray(y_r, np.float32),
-                               atol=tol, rtol=tol)
     np.testing.assert_allclose(np.asarray(y_r, np.float32), np.asarray(y_d, np.float32),
                                atol=tol, rtol=tol)
     np.testing.assert_allclose(np.asarray(y_r, np.float32), np.asarray(y_m, np.float32),
                                atol=tol, rtol=tol)
 
 
-def test_nm_spmm_custom_vjp_matches_dense_autodiff():
-    x, w, wc, idx, mask, spec = _mk_sparse(1, 64, 32, 8, 16, 1, 2, jnp.float32)
-    dy = jax.random.normal(jax.random.PRNGKey(7), (16, 32))
-
-    f = lambda x_, wc_: (nm_ops.nm_spmm(x_, wc_, idx, True) * dy).sum()
-    gx, gwc = jax.grad(f, argnums=(0, 1))(x, wc)
-    fd = lambda x_, wd_: ((x_ @ wd_) * dy).sum()
-    gxd, gwd = jax.grad(fd, argnums=(0, 1))(x, nm_ref.densify(wc, idx, 64))
-    gwd_c, _ = nm_ops.make_compact(gwd, mask, 8, 16)
-    np.testing.assert_allclose(gx, gxd, atol=1e-5)
-    np.testing.assert_allclose(gwc, gwd_c, atol=1e-5)
+@pytest.mark.parametrize("k,o,bk,bo,n,m", NM_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_nm_spmm_deltas_vs_dense(k, o, bk, bo, n, m, dtype):
+    """Per-slot compact deltas sharing one idx: slot s contracts with its
+    own densified delta, never a neighbour's."""
+    x, _, wc, idx, _, _ = _mk_sparse(1, k, o, bk, bo, n, m, dtype)
+    S = x.shape[0]
+    delta = jax.random.normal(jax.random.PRNGKey(9),
+                              (S,) + wc.shape).astype(dtype)
+    tol = 1e-5 if dtype == jnp.float32 else 5e-2
+    got = nm_ref.nm_spmm_deltas(x, delta, idx)
+    dense = jnp.stack([nm_ref.densify(delta[s], idx, k) for s in range(S)])
+    want = jnp.einsum("sk,skn->sn", x, dense)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
 
 
 def test_nm_spmm_flop_scaling():
-    """Kernel work scales with n/m: the compact layout only visits kept blocks."""
+    """Work scales with n/m: the compact layout only visits kept blocks."""
     _, _, wc, idx, _, _ = _mk_sparse(0, 128, 128, 16, 32, 2, 8, jnp.float32)
     assert wc.shape[1] == idx.shape[1] == 2 * (128 // 16 // 8)   # G*n kept blocks
     assert wc.size == 128 * 128 * 2 // 8                         # density × dense
 
 
-@pytest.mark.parametrize("shape", [(8, 128), (16, 256), (8, 250), (5, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_lif_kernel_sweep(shape, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    v = jax.random.normal(ks[0], shape).astype(dtype)
-    tr = jax.random.uniform(ks[1], shape).astype(dtype)
-    cur = jax.random.normal(ks[2], shape).astype(dtype)
-    kw = dict(alpha=0.9, beta=0.85, theta=1.0)
-    got = lif_ops.lif_step(v, tr, cur, interpret=True, **kw)
-    want = lif_ref.lif_step(v, tr, cur, **kw)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g, np.float32),
-                                   np.asarray(w, np.float32), atol=1e-4)
-
-
-def test_lif_kernel_direct_tiles():
-    v = jax.random.normal(jax.random.PRNGKey(0), (16, 256))
-    tr = jnp.zeros((16, 256))
-    cur = jax.random.normal(jax.random.PRNGKey(1), (16, 256))
-    a = lif_pallas(v, tr, cur, alpha=0.5, beta=0.9, theta=0.7, bm=8, bn=128,
-                   interpret=True)
-    b = lif_ref.lif_step(v, tr, cur, alpha=0.5, beta=0.9, theta=0.7)
-    for x, y in zip(a, b):
-        np.testing.assert_allclose(x, y, atol=1e-5)
-
-
-@pytest.mark.parametrize("b,k,o,bk,bo,bb", [(8, 32, 16, 4, 8, 4),
-                                            (16, 64, 32, 8, 16, 8),
-                                            (4, 16, 8, 4, 8, 4)])
-def test_wu_outer_sweep(b, k, o, bk, bo, bb):
-    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+def _kept_idx(seed, k, o, bk, bo):
     spec = sp.NMSpec(n=1, m=2, block=bk, out_tile=bo)
-    mask = sp.random_unit_mask(ks[0], spec, k, o)
+    mask = sp.random_unit_mask(jax.random.PRNGKey(seed), spec, k, o)
     _, idx = nm_ops.make_compact(jnp.zeros((k, o)), mask, bk, bo)
-    pre = jax.random.normal(ks[1], (b, k))
-    mod = jax.random.normal(ks[2], (b, o))
-    scale = jnp.float32(0.05)
-    got = wu_outer_pallas(pre, mod, idx, scale, bk=bk, bo=bo, bb=bb, interpret=True)
-    want = wu_ref.wu_outer(pre, mod, idx, scale, bk, bo)
-    np.testing.assert_allclose(got, want, atol=1e-5)
+    dm = sp.expand_unit_mask(mask, spec, k, o).astype(jnp.float32)
+    return idx, dm
+
+
+@pytest.mark.parametrize("s,k,o,bk,bo", [(8, 32, 16, 4, 8),
+                                         (16, 64, 32, 8, 16),
+                                         (4, 16, 8, 4, 8)])
+def test_wu_outer_sweep(s, k, o, bk, bo):
+    """Each slot's compact update equals its own dense outer product at the
+    kept coordinates; a slot whose scale is 0 gets exactly nothing."""
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    idx, dm = _kept_idx(0, k, o, bk, bo)
+    pre = jax.random.normal(ks[0], (s, k))
+    mod = jax.random.normal(ks[1], (s, o))
+    scale = jax.random.uniform(ks[2], (s,), minval=0.01, maxval=0.1)
+    scale = scale.at[::3].set(0.0)
+    got = wu_ref.wu_outer_slots(pre, mod, idx, scale, bk, bo)
+    dense = jnp.stack([nm_ref.densify(got[i], idx, k) for i in range(s)])
+    want = scale[:, None, None] * pre[:, :, None] * mod[:, None, :] * dm
+    np.testing.assert_allclose(dense, want, atol=1e-6)
+    assert float(jnp.abs(got[::3]).max()) == 0.0
 
 
 def test_wu_outer_gate_zero_is_noop():
     """A gated-off layer's WU is exactly zero (the skip the chip doesn't pay for)."""
-    spec = sp.NMSpec(n=1, m=2, block=4, out_tile=8)
-    mask = sp.random_unit_mask(jax.random.PRNGKey(0), spec, 16, 8)
-    _, idx = nm_ops.make_compact(jnp.zeros((16, 8)), mask, 4, 8)
+    idx, _ = _kept_idx(0, 16, 8, 4, 8)
     pre = jax.random.normal(jax.random.PRNGKey(1), (8, 16))
     mod = jax.random.normal(jax.random.PRNGKey(2), (8, 8))
-    out = wu_ref.wu_outer(pre, mod, idx, jnp.float32(0.0), 4, 8)
+    out = wu_ref.wu_outer_slots(pre, mod, idx, jnp.zeros((8,)), 4, 8)
     assert float(jnp.abs(out).max()) == 0.0
 
 

@@ -2,16 +2,15 @@
 
 The TPU compiler ships with libtpu and compiles for a chip that is
 described rather than attached, so these tests refuse here what the chip's
-compiler would refuse — a block shape Mosaic cannot tile, a program that
-does not fit 16 GB of HBM, a collective inside the slot-sharded step —
-while interpret-mode kernel tests cannot. Nothing runs: results and times
+compiler would refuse — a program that does not fit 16 GB of HBM, a
+collective inside the slot-sharded step, a copy where the program should
+update in place. Nothing runs: results and times
 still come only from a chip run (``chip_smoke.py``).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load libtpu, and test collection must not
 depend on whether this process got it.
 """
-import dataclasses
 import os
 import re
 
@@ -22,7 +21,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs.elfcore_snn import CONFIG
-from repro.core import engine, snn
+from repro.core import snn
 from repro.serving.adapt import make_chunk_fn
 
 # the serving grid chip_smoke.py drives: ~1024 gesture streams, T=50
@@ -119,48 +118,6 @@ def test_training_step_fits_one_chip(one_chip):
         *(_shapes(a, one_chip) for a in (p, st, ev, lab))).compile()
     assert 0 < _device_bytes(compiled) < HBM_BYTES, \
         compiled.memory_analysis()
-
-
-def _nm_spmm(one_chip, rows, k, n, bk, bo):
-    from repro.kernels.nm_spmm.ops import nm_spmm_batched
-    t = (k // bk) // 2
-    return nm_spmm_batched, (
-        jax.ShapeDtypeStruct((rows, k), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((n // bo, t, bk, bo), jnp.float32,
-                             sharding=one_chip),
-        jax.ShapeDtypeStruct((n // bo, t), jnp.int32, sharding=one_chip))
-
-
-def _lif(one_chip, rows, k, n, bk, bo):
-    from repro.kernels.lif.ops import lif_step
-    fn = lambda v, tr, cur: lif_step(v, tr, cur, alpha=CONFIG.alpha,
-                                     beta=CONFIG.beta, theta=CONFIG.theta)
-    return fn, tuple(jax.ShapeDtypeStruct((rows, n), jnp.float32,
-                                          sharding=one_chip)
-                     for _ in range(3))
-
-
-def _wu_outer(one_chip, rows, k, n, bk, bo):
-    from repro.kernels.wu_outer.ops import wu_outer
-    t = (k // bk) // 2
-    fn = lambda pre, mod, idx, scale: wu_outer(pre, mod, idx, scale,
-                                               bk=bk, bo=bo)
-    return fn, (
-        jax.ShapeDtypeStruct((rows, k), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((rows, n), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((n // bo, t), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip))
-
-
-@pytest.mark.parametrize("kernel", [_nm_spmm, _lif, _wu_outer],
-                         ids=["nm_spmm", "lif", "wu_outer"])
-def test_kernel_compiles_to_mosaic(one_chip, kernel):
-    """The paper's widths (K = N = 512) at 128-aligned N:M blocks: the
-    compiled program holds the Mosaic kernel itself."""
-    fn, args = kernel(one_chip, rows=256, k=CONFIG.n_in, n=CONFIG.n_hidden,
-                      bk=128, bo=128)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_sharded_chunk_fn_has_no_collectives(topo):
@@ -287,17 +244,3 @@ def test_lane_reset_is_in_place(topo, chips):
     assert m.temp_size_in_bytes < grid // 100, m
     text = compiled.as_text()
     assert not COLLECTIVE.search(text), COLLECTIVE.findall(text)[:5]
-
-
-def test_pallas_backend_refuses_untileable_spec():
-    """The paper's element-granular N:M spec (block = out_tile = 1) cannot
-    tile onto the compiled kernels: refused at the seam, not in Mosaic."""
-    cfg = dataclasses.replace(CONFIG, backend="pallas")
-    assert cfg.spec(cfg.n_in).block == 1
-    with pytest.raises(ValueError, match="multiples of 128"):
-        engine.make_backend(cfg)
-    with pytest.raises(ValueError, match="multiples of 128"):
-        snn.make_train_fn(cfg)(
-            snn.init_params(jax.random.PRNGKey(0), cfg),
-            snn.init_state(cfg, 2),
-            jnp.zeros((cfg.t_steps, 2, cfg.n_in)), jnp.zeros((2,), jnp.int32))

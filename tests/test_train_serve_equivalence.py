@@ -6,7 +6,10 @@ driven with all-valid, window-aligned chunks from zero deltas must retrace
 drift (base updates ≡ accumulated deltas, by linearity of the forward
 current), and telemetry — at every depth. One stream vs batch-of-one, so
 the training path's batch-shared gate decisions coincide with the serving
-path's per-slot decisions.
+path's per-slot decisions. Beyond the uniform default the same trajectory
+is pinned where layer fan-ins differ (``n_in=24``: padded layer stack and,
+from depth 2, dense ``[S, L, Kmax, N]`` deltas) and on the dense baseline
+(``dense=True``).
 """
 import dataclasses
 
@@ -25,14 +28,21 @@ N_WINDOWS = 2
 CHUNK = 6   # divides t_steps: chunks are window-aligned
 
 
-def _cfg(depth):
-    return SNNConfig(n_in=32, n_hidden=32, n_layers=depth, n_out=8,
-                     t_steps=12, dsst_enabled=False)
+GEOMETRIES = {"uniform": {}, "fanin24": dict(n_in=24),
+              "dense": dict(dense=True)}
 
 
-@pytest.mark.parametrize("depth", [1, 2, 3])
-def test_chunk_trajectory_matches_sample(depth):
-    cfg = _cfg(depth)
+def _cfg(depth, geometry="uniform"):
+    return SNNConfig(**{**dict(n_in=32, n_hidden=32, n_layers=depth, n_out=8,
+                               t_steps=12, dsst_enabled=False),
+                        **GEOMETRIES[geometry]})
+
+
+@pytest.mark.parametrize("geometry,depth", [
+    pytest.param(g, d, id=str(d) if g == "uniform" else f"{g}-{d}")
+    for g in GEOMETRIES for d in (1, 2, 3)])
+def test_chunk_trajectory_matches_sample(geometry, depth):
+    cfg = _cfg(depth, geometry)
     T = cfg.t_steps
     t_wu = int(T * cfg.wu_start_frac)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -75,11 +85,14 @@ def test_chunk_trajectory_matches_sample(depth):
         np.testing.assert_allclose(a, b, atol=1e-5)
 
     # weight drift: in-place base updates == accumulated per-stream delta
-    # (serving deltas come back compact [S, L, J, T, bk, bo]; densify over
-    # the frozen base's kept-block ids for the dense comparison)
-    from repro.core import topology
-    idx = topology.stacked_kept_ids(params["hidden"]["mask"], cfg)
-    dl_dense = engine.densify_deltas(dl, idx, cfg)
+    # (uniform fan-ins serve compact [S, L, J, T, bk, bo] deltas; densify
+    # over the frozen base's kept-block ids for the dense comparison)
+    assert dl.ndim == (6 if engine.geometry(cfg).uniform else 4), dl.shape
+    if dl.ndim == 6:
+        from repro.core import topology
+        idx = topology.stacked_kept_ids(params["hidden"]["mask"], cfg)
+        dl = engine.densify_deltas(dl, idx, cfg)
+    dl_dense = dl
     drift = np.asarray(ps["hidden"]["w"] - params["hidden"]["w"])
     np.testing.assert_allclose(drift, np.asarray(dl_dense[0]), atol=1e-5)
     # labels never entered: readout identical on both paths
