@@ -17,6 +17,11 @@ latency buckets are spaced ~10% apart).
 Counters are monotone *by construction*: a negative increment raises
 instead of silently un-counting — the property the CI Prometheus smoke
 scrapes for.
+
+A family whose children are many and updated together (one per served
+stream) keeps their values in a :class:`Table`, one row per label set:
+each child is a :class:`TableCell` view of its row, so a fold over every
+label set is one array add and the scrape reads the same cells.
 """
 from __future__ import annotations
 
@@ -24,6 +29,8 @@ import math
 import re
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -98,6 +105,66 @@ class Gauge:
     @property
     def value(self) -> float:
         return self._value
+
+
+class Table:
+    """Growable float64 ``[rows, cols]`` store of many children's values.
+
+    Rows are allocated on first use and the array doubles when full.
+    ``monotone[col]`` marks a counter column: :meth:`add` raises on a
+    negative increment there before adding anything. One lock guards
+    growth and every add."""
+    __slots__ = ("data", "n_rows", "monotone", "lock")
+
+    def __init__(self, monotone: Sequence[bool], capacity: int = 64):
+        self.monotone = np.asarray(monotone, bool)
+        self.data = np.zeros((capacity, len(self.monotone)))
+        self.n_rows = 0
+        self.lock = threading.Lock()
+
+    def add_row(self) -> int:
+        """A new zero row's index."""
+        with self.lock:
+            if self.n_rows == len(self.data):
+                self.data = np.concatenate(
+                    [self.data, np.zeros_like(self.data)])
+            self.n_rows += 1
+            return self.n_rows - 1
+
+    def add(self, rows, inc) -> None:
+        """``data[rows] += inc`` in one array op; ``rows`` must be distinct
+        (a repeated row would receive only one of its increments)."""
+        inc = np.asarray(inc, np.float64)
+        if (inc[:, self.monotone] < 0).any():
+            raise ValueError("counter increments must be >= 0, got "
+                             f"{inc[:, self.monotone].min()}")
+        with self.lock:
+            self.data[rows] += inc
+
+    def sums(self) -> np.ndarray:
+        """Each column summed over the allocated rows."""
+        with self.lock:
+            return self.data[:self.n_rows].sum(axis=0)
+
+
+class TableCell:
+    """A registry child whose value is one cell of a :class:`Table`;
+    ``inc`` keeps the column's rule (a counter cell raises on a negative
+    increment)."""
+    __slots__ = ("_table", "_row", "_col")
+
+    def __init__(self, table: Table, row: int, col: int):
+        self._table, self._row, self._col = table, row, col
+
+    def inc(self, amount: float = 1.0) -> None:
+        if amount < 0 and self._table.monotone[self._col]:
+            raise ValueError(f"counter increments must be >= 0, got {amount}")
+        with self._table.lock:
+            self._table.data[self._row, self._col] += amount
+
+    @property
+    def value(self) -> float:
+        return float(self._table.data[self._row, self._col])
 
 
 class Histogram:
@@ -181,13 +248,26 @@ class Family:
         self._children: Dict[Tuple[str, ...], object] = {}
         self._lock = threading.Lock()
 
-    def labels(self, **labelvalues):
-        """The child for this label-value combination."""
+    def _key(self, labelvalues) -> Tuple[str, ...]:
         if set(labelvalues) != set(self.labelnames):
             raise ValueError(
                 f"{self.name}: expected labels {self.labelnames}, "
                 f"got {tuple(labelvalues)}")
-        key = tuple(str(labelvalues[n]) for n in self.labelnames)
+        return tuple(str(labelvalues[n]) for n in self.labelnames)
+
+    def bind(self, child, **labelvalues) -> None:
+        """Install ``child`` (e.g. a :class:`TableCell`) for a label set
+        that has none yet."""
+        key = self._key(labelvalues)
+        with self._lock:
+            if key in self._children:
+                raise ValueError(f"{self.name}{key} already has a child")
+            # lint: ok OBS01 — label cardinality is caller-bounded
+            self._children[key] = child
+
+    def labels(self, **labelvalues):
+        """The child for this label-value combination."""
+        key = self._key(labelvalues)
         with self._lock:
             child = self._children.get(key)
             if child is None:

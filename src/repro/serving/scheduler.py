@@ -130,15 +130,19 @@ class _Tier:
     state/deltas (+ shardings), compiled chunk fn and lane-reset program,
     and staging pipeline.
     ``slot0`` is the tier's offset in the fleet-global slot numbering
-    (``step()`` returns global slot ids; everything internal is local)."""
+    (``step()`` returns global slot ids; everything internal is local).
+    ``lane_row[slot]`` is the occupant's row of the telemetry's counter
+    table, written at admission (stale on a freed lane until the next)."""
 
     __slots__ = ("name", "chunk_len", "n_slots", "slot0", "grid", "state",
-                 "deltas", "chunk_fn", "reset_lanes", "pipeline", "state_sh")
+                 "deltas", "chunk_fn", "reset_lanes", "pipeline", "state_sh",
+                 "lane_row")
 
     def __init__(self, name: str, chunk_len: int, n_slots: int, slot0: int):
         self.name, self.chunk_len = name, chunk_len
         self.n_slots, self.slot0 = n_slots, slot0
         self.state_sh = None
+        self.lane_row = np.zeros(n_slots, np.intp)
 
 
 class StreamScheduler:
@@ -180,8 +184,9 @@ class StreamScheduler:
         (``sched.step/stage/poll_sources/admit/dispatch/retire/
         device_wait``, ``topology.epoch``, ``autopilot.decision/apply``)
         and their sub-spans (``admit.write``, ``dispatch.transfer/
-        enqueue``, ``retire.deliver/telemetry/snapshot``); the shared
-        no-op ``NULL_TRACER`` by default. Spans
+        enqueue``, ``retire.deliver/telemetry/snapshot``; the counter
+        fold's ``retire.telemetry`` carries the ``lanes`` it folded); the
+        shared no-op ``NULL_TRACER`` by default. Spans
         only time host work the scheduler does anyway — tracing on vs.
         off is bit-identical and leaves the serving jaxpr unchanged
         (pinned in ``tests/test_obs_serving.py``).
@@ -373,6 +378,27 @@ class StreamScheduler:
             for leaf in jax.tree_util.tree_leaves(self._exec_params))
         self._delta_bytes = sum(int(t.deltas.nbytes) for t in self._tiers)
 
+    @property
+    def telemetry(self) -> FleetTelemetry:
+        """Where the fleet's counters and timings land."""
+        return self._telemetry
+
+    @telemetry.setter
+    def telemetry(self, telemetry: FleetTelemetry) -> None:
+        """Swap the sink: the occupied lanes' counter rows, and those the
+        in-flight steps staged, are re-mapped into the new table."""
+        self._telemetry = telemetry
+        for tier in self._tiers:
+            occupied = [(slot, sess.sid)
+                        for slot, sess in enumerate(tier.grid.occupant)
+                        if sess is not None]
+            if occupied:
+                slots, sids = zip(*occupied)
+                tier.lane_row[list(slots)] = telemetry.rows(sids)
+            for fl in tier.pipeline:
+                fl.staged.rows = telemetry.rows(
+                    [rec.session.sid for rec in fl.staged.lanes])
+
     # -- lifecycle -----------------------------------------------------------
     def submit(self, session: StreamSession,
                tier: Optional[str] = None) -> None:
@@ -432,6 +458,8 @@ class StreamScheduler:
             if n:
                 for slot, sess in admitted:
                     sess.slot, sess.status = slot, SessionStatus.ACTIVE
+                tier.lane_row[[slot for slot, _ in admitted]] = \
+                    self.telemetry.rows([sess.sid for _, sess in admitted])
                 for fl in tier.pipeline:
                     if fl.deltas is tier.deltas:
                         fl.take_snapshots()
@@ -533,11 +561,10 @@ class StreamScheduler:
                 retiring.append((slot, sess))
         valid = np.arange(C)[:, None] < n_fed      # [C, S]: ragged tails off
         # each lane's exact spike count: the set bits of its column
-        spikes = (np.bitwise_count(events).sum(axis=0, dtype=np.int32)
-                  .sum(axis=1).tolist() if occupied else [])
-        lanes = [LaneRecord(slot=slot, session=sess, n_fed=n,
-                            events_in=float(spikes[slot]))
+        spikes = np.bitwise_count(events).sum(axis=0, dtype=np.int32).sum(1)
+        lanes = [LaneRecord(slot=slot, session=sess, n_fed=n)
                  for slot, sess, n in occupied]
+        slots = np.array([slot for slot, _, _ in occupied], np.intp)
         fed = {slot: n for slot, _, n in occupied}
         gone = {slot for slot, _ in retiring}
         merge_slots = tuple(
@@ -545,7 +572,8 @@ class StreamScheduler:
             if sess is not None and sess.adapt and slot not in gone)
         return StagedChunk(events=events, valid=valid, adapt_mask=amask,
                            lanes=lanes, retiring=retiring,
-                           merge_slots=merge_slots, fed=fed)
+                           merge_slots=merge_slots, fed=fed, slots=slots,
+                           spikes=spikes, rows=tier.lane_row[slots])
 
     # -- phase 2: dispatch ---------------------------------------------------
     def _dispatch(self, tier: _Tier, staged: StagedChunk) -> InFlight:
@@ -634,7 +662,8 @@ class StreamScheduler:
                     sess.predictions.append(WindowPrediction(
                         window_idx=len(sess.predictions),
                         logits=logits[t, slot].copy()))
-        with self.tracer.span("retire.telemetry", grid_step=fl.grid_step):
+        with self.tracer.span("retire.telemetry", grid_step=fl.grid_step,
+                              lanes=len(staged.slots)):
             self._fold_telemetry(tier, staged, m)
         with self.tracer.span("retire.snapshot", grid_step=fl.grid_step,
                               retiring=len(staged.retiring)) as sp:
@@ -665,42 +694,20 @@ class StreamScheduler:
                                        grid_step=fl.grid_step)
 
     def _fold_telemetry(self, tier: _Tier, staged: StagedChunk, m) -> None:
-        """Fold each lane's slice of the chunk metrics into its stream's
-        counters, and the lanes' sums into the tier's."""
-        wend = m.window_end                    # [C, S]
-        tsum = {"steps": 0.0, "events_in": 0.0, "sop_forward": 0.0,
-                "sop_wu": 0.0, "sop_wu_offered": 0.0, "windows": 0}
-        for rec in staged.lanes:
-            slot, sess = rec.slot, rec.session
-            steps = float(m.steps[slot])
-            sop_forward = float(m.sop_forward[slot])
-            sop_wu = float(m.sop_wu[slot])
-            sop_wu_offered = float(m.sop_wu_offered[slot])
-            windows = int(wend[:, slot].sum())
-            counters = self.telemetry.stream(sess.sid)
-            counters.add_chunk(
-                steps=steps,
-                events_in=rec.events_in,
-                sop_forward=sop_forward,
-                sop_wu=sop_wu,
-                sop_wu_offered=sop_wu_offered,
-                gate_opened=float(m.gate_opened[slot].sum()),
-                gate_offered=float(m.gate_offered[slot].sum()),
-                windows=windows,
-                local_loss=float(m.local_loss[slot]))
-            tsum["steps"] += steps
-            tsum["events_in"] += rec.events_in
-            tsum["sop_forward"] += sop_forward
-            tsum["sop_wu"] += sop_wu
-            tsum["sop_wu_offered"] += sop_wu_offered
-            tsum["windows"] += windows
-        if staged.lanes:
-            self.telemetry.record_tier_chunk(
-                tier.name, timesteps=tsum["steps"],
-                events_in=tsum["events_in"],
-                sop_forward=tsum["sop_forward"], sop_wu=tsum["sop_wu"],
-                sop_wu_offered=tsum["sop_wu_offered"],
-                windows=tsum["windows"])
+        """Fold the occupied lanes' slices of the chunk metrics into their
+        streams' counter rows, and their sums into the tier's: a fixed
+        number of array ops, however many lanes. Each stream still gets
+        one float64 add per column of the same float32-derived value."""
+        s = staged.slots
+        if not len(s):
+            return
+        self.telemetry.fold_streams(
+            tier.name, staged.rows, timesteps=m.steps[s],
+            events_in=staged.spikes[s], sop_forward=m.sop_forward[s],
+            sop_wu=m.sop_wu[s], sop_wu_offered=m.sop_wu_offered[s],
+            gate_opened=m.gate_opened[s].sum(1),
+            gate_offered=m.gate_offered[s].sum(1),
+            windows=m.window_end[:, s].sum(0), local_loss=m.local_loss[s])
 
     # -- adaptive depth ------------------------------------------------------
     def _apply_autopilot(self) -> None:

@@ -52,7 +52,6 @@ class LaneRecord:
     slot: int
     session: Any                 # StreamSession
     n_fed: int                   # timesteps packed into the lane
-    events_in: float             # total input spikes packed (telemetry)
 
 
 @dataclasses.dataclass
@@ -68,6 +67,12 @@ class StagedChunk:
     retire sees the same candidate set the serial scheduler would —
     admissions from *later* stage phases must not leak into an earlier
     step's epoch).
+
+    ``slots`` (the occupied lanes, in ``lanes`` order), ``spikes`` (each
+    lane's packed spike count, ``[S]``) and ``rows`` (each occupied lane's
+    row of the telemetry's counter table, gathered at stage, so a lane
+    re-admitted before this step retires still folds into its old
+    stream) are what retire folds the step's counters from, as arrays.
     """
     events: Any                  # np.ndarray [C, S, ceil(n_in/8)] uint8
     valid: Any                   # np.ndarray [C, S] bool
@@ -76,6 +81,9 @@ class StagedChunk:
     retiring: List[Tuple[int, Any]]
     merge_slots: Tuple[int, ...]
     fed: Dict[int, int]          # {slot: timesteps fed} (step() return value)
+    slots: Any = None            # np.ndarray [n_occupied] intp
+    spikes: Any = None           # np.ndarray [S] int
+    rows: Any = None             # np.ndarray [n_occupied] intp
 
 
 @dataclasses.dataclass

@@ -5,7 +5,7 @@ The chip's power story is counted events priced at measured constants
 a fleet operator can answer "which streams are hot, which are coasting on
 the gate, what does a slot-second cost". Counters are monotone by
 construction — every update adds a non-negative per-chunk quantity (the
-``obs.metrics.Counter`` underneath *raises* on a negative increment) —
+``obs.metrics.Table`` underneath *raises* on a negative increment) —
 and per-stream separable: a slot's counters only ever receive that slot's
 lane of the chunk metrics.
 
@@ -16,6 +16,12 @@ times land in **bounded fixed-bucket histograms** (the old unbounded
 ``step_latencies_s`` list is gone — memory is O(1) in steps, p50/p99 are
 interpolated within ~10% bucket width), and the whole registry exports as
 Prometheus text / JSONL / a benchmark artifact via ``repro.obs.export``.
+
+The per-stream values live in one float64 table (``obs.metrics.Table``),
+a row per sid ever served and a column per :data:`STREAM_COLUMNS` entry;
+the ``sid`` children of those families are views of its cells, so the
+scheduler's retire folds every lane of a grid step with one array add
+(:meth:`FleetTelemetry.fold_streams`) and a scrape reads the same cells.
 
 Beyond whole-step wall time the telemetry now attributes **per-phase**
 wall (stage / dispatch / retire / flush — fed by the scheduler's spans,
@@ -31,11 +37,14 @@ from __future__ import annotations
 
 import threading
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Sequence
+
+import numpy as np
 
 from repro.core.energy import OperatingPoint, report
 from repro.obs.metrics import (LATENCY_BUCKETS_S, QUEUE_DEPTH_BUCKETS,
-                               RATIO_BUCKETS, MetricsRegistry)
+                               RATIO_BUCKETS, MetricsRegistry, Table,
+                               TableCell)
 
 # every per-stream counter family: attribute name -> (metric name, help)
 STREAM_COUNTER_FAMILIES = {
@@ -63,6 +72,10 @@ STREAM_GAUGE_FAMILIES = {
                    "summed local OSSL loss"),
 }
 
+# the counter table's columns, in order: the counters, then local_loss
+STREAM_COLUMNS = (*STREAM_COUNTER_FAMILIES, *STREAM_GAUGE_FAMILIES)
+_COLUMN = {attr: col for col, attr in enumerate(STREAM_COLUMNS)}
+
 PHASES = ("stage", "dispatch", "retire", "flush")
 
 # per-tier QoS counter families: attribute name -> (metric name, help).
@@ -89,46 +102,40 @@ TIER_COUNTER_FAMILIES = {
 class StreamCounters:
     """Monotone per-stream event counters (energy-model inputs).
 
-    A view over one ``sid``'s children of the registry's labeled counter
-    families: reads (``c.timesteps`` etc.) pull the live counter values,
-    :meth:`add_chunk` increments them. Negative increments raise in the
-    counter itself — monotonicity is enforced, not just asserted.
+    A view of one ``sid``'s row of the fleet's counter table: reads
+    (``c.timesteps`` etc.) return the live cells, which are also the
+    registry's ``{sid}`` children of the stream families. The counter
+    columns are monotone by construction — a negative increment raises
+    before anything is added.
     """
 
-    def __init__(self, sid: int, registry: Optional[MetricsRegistry] = None):
+    def __init__(self, sid: int, table: Table, registry: MetricsRegistry):
         self.sid = sid
-        registry = registry or MetricsRegistry()
-        self._c = {
-            attr: registry.counter(name, help, labels=("sid",))
-                          .labels(sid=str(sid))
-            for attr, (name, help) in STREAM_COUNTER_FAMILIES.items()}
-        self._c.update({
-            attr: registry.gauge(name, help, labels=("sid",))
-                          .labels(sid=str(sid))
-            for attr, (name, help) in STREAM_GAUGE_FAMILIES.items()})
+        self.row = row = table.add_row()
+        self._table = table
+        for attr, (name, help) in STREAM_COUNTER_FAMILIES.items():
+            registry.counter(name, help, labels=("sid",)).bind(
+                TableCell(table, row, _COLUMN[attr]), sid=str(sid))
+        for attr, (name, help) in STREAM_GAUGE_FAMILIES.items():
+            registry.gauge(name, help, labels=("sid",)).bind(
+                TableCell(table, row, _COLUMN[attr]), sid=str(sid))
 
     def __getattr__(self, attr):
-        try:
-            child = self.__dict__["_c"][attr]
-        except KeyError:
-            raise AttributeError(attr) from None
-        return int(child.value) if attr == "windows" else child.value
+        col = _COLUMN.get(attr)
+        if col is None or "_table" not in self.__dict__:
+            raise AttributeError(attr)
+        value = float(self._table.data[self.row, col])
+        return int(value) if attr == "windows" else value
 
     def add_chunk(self, *, steps, events_in, sop_forward, sop_wu,
                   sop_wu_offered, gate_opened, gate_offered, windows,
                   local_loss) -> None:
         """Fold one grid step's slice of the chunk metrics into this
-        stream's counters (all non-negative scalars — a negative one is a
-        bug upstream and raises here)."""
-        self._c["timesteps"].inc(float(steps))
-        self._c["events_in"].inc(float(events_in))
-        self._c["sop_forward"].inc(float(sop_forward))
-        self._c["sop_wu"].inc(float(sop_wu))
-        self._c["sop_wu_offered"].inc(float(sop_wu_offered))
-        self._c["gate_opened"].inc(float(gate_opened))
-        self._c["gate_offered"].inc(float(gate_offered))
-        self._c["windows"].inc(int(windows))
-        self._c["local_loss"].inc(float(local_loss))
+        stream's row (the single-row form of
+        :meth:`FleetTelemetry.fold_streams`)."""
+        inc = [steps, events_in, sop_forward, sop_wu, sop_wu_offered,
+               gate_opened, gate_offered, int(windows), local_loss]
+        self._table.add([self.row], np.array([inc], np.float64))
 
     @property
     def wu_skip_rate(self) -> float:
@@ -164,6 +171,8 @@ class FleetTelemetry:
         self.op = op or OperatingPoint.low_power()
         self.registry = registry or MetricsRegistry()
         self.streams: Dict[int, StreamCounters] = {}
+        self._table = Table([attr in STREAM_COUNTER_FAMILIES
+                             for attr in STREAM_COLUMNS])
         self._lock = threading.Lock()
         self._steps = self.registry.counter(
             "serving_grid_steps_total", "scheduler grid steps dispatched")
@@ -271,9 +280,32 @@ class FleetTelemetry:
         the same record (never two counter children for one stream)."""
         with self._lock:
             if sid not in self.streams:
-                self.streams[sid] = StreamCounters(sid, self.registry)
+                self.streams[sid] = StreamCounters(
+                    sid, self._table, self.registry)
                 self._stream_series.set(float(len(self.streams)))
             return self.streams[sid]
+
+    def rows(self, sids: Sequence[int]) -> np.ndarray:
+        """The counter-table rows of ``sids`` (records created on first
+        use), as an index array for :meth:`fold_streams`."""
+        return np.array([self.stream(sid).row for sid in sids], np.intp)
+
+    def fold_streams(self, tier: str, rows: np.ndarray, **lanes) -> None:
+        """Fold one retired grid step into the distinct counter rows
+        ``rows [n]`` with one array add, and the lanes' sums into
+        ``tier``'s counters. ``lanes`` gives every :data:`STREAM_COLUMNS`
+        entry as an ``[n]`` array. A negative counter increment raises
+        before anything is added."""
+        inc = np.stack([lanes[attr] for attr in STREAM_COLUMNS], axis=1,
+                       dtype=np.float64)
+        with self._lock:
+            self._table.add(rows, inc)
+        total = dict(zip(STREAM_COLUMNS, inc.sum(0).tolist()))
+        self.record_tier_chunk(
+            tier, timesteps=total["timesteps"],
+            events_in=total["events_in"], sop_forward=total["sop_forward"],
+            sop_wu=total["sop_wu"], sop_wu_offered=total["sop_wu_offered"],
+            windows=total["windows"])
 
     def record_step(self, latency_s: float) -> None:
         """Log one grid step's host wall time (one ``step()`` call — under
@@ -493,22 +525,19 @@ class FleetTelemetry:
         latency percentiles, overlap ratio, fleet energy, and the topology
         rollup. See docs/SERVING.md / docs/OBSERVABILITY.md for the field
         glossary."""
-        def fam_total(attr):
-            fam = self.registry.get(STREAM_COUNTER_FAMILIES[attr][0])
-            return fam.total() if fam is not None else 0.0
-
-        timesteps = fam_total("timesteps")
-        events_in = fam_total("events_in")
-        sop_forward = fam_total("sop_forward")
-        sop_wu = fam_total("sop_wu")
-        sop_wu_offered = fam_total("sop_wu_offered")
+        total = dict(zip(STREAM_COLUMNS, self._table.sums().tolist()))
+        timesteps = total["timesteps"]
+        events_in = total["events_in"]
+        sop_forward = total["sop_forward"]
+        sop_wu = total["sop_wu"]
+        sop_wu_offered = total["sop_wu_offered"]
         wall = self._step_hist.sum + self._flush_wall.value
         out = {
             "n_streams": len(self.streams),
             "grid_steps": self.steps,
             "timesteps": timesteps,
             "events_in": events_in,
-            "windows": int(fam_total("windows")),
+            "windows": int(total["windows"]),
             "wu_skip_rate": (1.0 - sop_wu / sop_wu_offered
                              if sop_wu_offered > 0 else 0.0),
             "fleet_energy": report(sop_forward, sop_wu, sop_wu_offered,

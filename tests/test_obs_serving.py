@@ -35,7 +35,8 @@ from repro.obs import Tracer, parse_prometheus_text, prometheus_text
 from repro.obs.metrics import LATENCY_BUCKETS_S
 from repro.serving import (ReplaySource, StreamScheduler, StreamSession,
                            TopologyService, TopologyServiceConfig)
-from repro.serving.telemetry import FleetTelemetry
+from repro.serving.telemetry import (STREAM_COUNTER_FAMILIES,
+                                     STREAM_GAUGE_FAMILIES, FleetTelemetry)
 
 CFG = SNNConfig(n_in=32, n_hidden=32, n_layers=2, n_out=8, t_steps=16)
 EVOLVE_CFG = SNNConfig(n_in=32, n_hidden=32, n_layers=2, n_out=8, t_steps=12,
@@ -576,9 +577,66 @@ def test_prometheus_scrape_of_live_run(frozen_runs):
                      'serving_stream_timesteps_total{sid="0"}',
                      'serving_stream_windows_total{sid="4"}'):
         assert required in parsed, required
-    # per-stream counters in the scrape == the in-process view
-    c0 = sched.telemetry.stream(0)
-    assert parsed['serving_stream_timesteps_total{sid="0"}'] == c0.timesteps
+    # per-stream counters in the scrape == the in-process view, for every
+    # stream of the churned fleet (5 streams through 3 lanes)
+    tel = sched.telemetry
+    assert sorted(tel.streams) == list(range(5))
+    families = {**STREAM_COUNTER_FAMILIES, **STREAM_GAUGE_FAMILIES}
+    for sid in tel.streams:
+        c = tel.stream(sid)
+        assert c.timesteps > 0, sid
+        for attr, (name, _) in families.items():
+            assert parsed[f'{name}{{sid="{sid}"}}'] == getattr(c, attr), \
+                (sid, attr)
+
+
+def test_swapped_telemetry_splits_the_counters(params, frozen_runs):
+    """Swapping ``sched.telemetry`` mid-run, with a step in flight, splits
+    each stream's counters between the two sinks at the swap: the two add
+    up to the unswapped run's (the loss sums to rounding)."""
+    whole = frozen_runs[0][0].telemetry
+    sched = StreamScheduler(params, CFG, n_slots=3, chunk_len=6,
+                            pipeline_depth=1)
+    for sid in range(5):
+        sched.submit(StreamSession(
+            sid=sid,
+            source=ReplaySource(_events(sid, (3 + sid % 2) * CFG.t_steps,
+                                        rate=0.25 + 0.03 * sid),
+                                chunk_len=7),
+            adapt=(sid % 2 == 0)))
+    while not sched.retired:        # swap once a lane has been re-admitted
+        sched.step()
+    sched.step()
+    assert len(sched.pipeline) == 1
+    assert {s.sid for s in sched.grid.occupant if s} & {3, 4}
+    first, sched.telemetry = sched.telemetry, FleetTelemetry()
+    sched.run_until_drained()
+    second = sched.telemetry
+    assert set(first.streams) | set(second.streams) == set(whole.streams)
+    for sid in whole.streams:
+        a, b, want = first.streams.get(sid), second.streams.get(sid), \
+            whole.stream(sid)
+        for attr in (*STREAM_COUNTER_FAMILIES, *STREAM_GAUGE_FAMILIES):
+            got = sum(getattr(c, attr) for c in (a, b) if c is not None)
+            if attr in STREAM_COUNTER_FAMILIES:
+                assert got == getattr(want, attr), (sid, attr)
+            else:
+                assert got == pytest.approx(getattr(want, attr), rel=1e-12)
+
+
+def test_retire_telemetry_counts_folded_lanes(frozen_runs):
+    """``retire.telemetry`` carries ``lanes``: the lanes its step staged,
+    which the step's dispatch counted too, including the draining steps
+    that ran with lanes empty."""
+    sched, _, _ = frozen_runs[1]
+    tr = sched.tracer
+    staged = {s.attr("grid_step"): s.attr("lanes")
+              for s in tr.spans("sched.dispatch")}
+    folds = {s.attr("grid_step"): s.attr("lanes")
+             for s in tr.spans("retire.telemetry")}
+    assert folds == staged
+    assert max(folds.values()) == sched.n_slots
+    assert min(folds.values()) < sched.n_slots
 
 
 # ------------------------------------------------------- overhead guard
